@@ -243,7 +243,10 @@ def run_kernel_ab(arch: str = "granite-3-2b", n_req=32, prompt=96, out=24,
     cfg = reduced(ARCHS[arch])
     model = build_model(cfg, single_device_dist())
     params = model.init(0)
-    from repro.serving.autotune import roofline_token_budget
+    from repro.serving.autotune import BudgetAutotuner, roofline_token_budget
+    # the autotuned leg seeds its budgets from a v5e's roofline whatever
+    # device runs this CPU benchmark
+    v5e = "TPU v5 lite"
     rows = {}
     for tag, impl, autotune in (("warmup", "ref", False),
                                 ("ref", "ref", False),
@@ -252,8 +255,12 @@ def run_kernel_ab(arch: str = "granite-3-2b", n_req=32, prompt=96, out=24,
         eng = Engine(model, EngineConfig(
             kv_pool_bytes=96 << 20, max_running=n_req, chunk_size=32,
             batching_mode="packed", attention_impl=impl,
-            autotune_budgets=autotune, max_num_batched_tokens=budget,
+            max_num_batched_tokens=budget,
             enable_prefix_caching=False), params=params)
+        if autotune:
+            eng.autotuner = BudgetAutotuner(cfg, v5e)
+            eng.scheduler.set_budgets(eng.autotuner.budget,
+                                      eng.autotuner.prefill_cap)
         for i in range(n_req):
             eng.submit(Request(rid=f"r{i}", prompt=[(7 * i + j) % 101
                                                     for j in range(prompt)],
@@ -286,7 +293,7 @@ def run_kernel_ab(arch: str = "granite-3-2b", n_req=32, prompt=96, out=24,
         del r["outputs"]        # equality asserted; keep the JSON small
     return dict(arch=arch, n_req=n_req, prompt=prompt, out=out,
                 budget_constant=budget,
-                budget_roofline_seed=roofline_token_budget(cfg),
+                budget_roofline_seed=roofline_token_budget(cfg, v5e),
                 ref=rows["ref"], kernel=rows["kernel"],
                 autotuned=rows["autotuned"])
 
@@ -577,4 +584,7 @@ def main(report=print, only: str = None):
 
 if __name__ == "__main__":
     import sys
+
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     main(only=sys.argv[1] if len(sys.argv) > 1 else None)

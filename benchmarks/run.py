@@ -7,6 +7,8 @@ import time
 
 
 def main() -> None:
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     from . import (bench_alloc_overhead, bench_batchsize,
                    bench_fragmentation, bench_prefix_cache,
                    bench_spec_decode, bench_throughput, bench_vision_cache)

@@ -1,6 +1,7 @@
 """Quickstart: train a reduced model for a few steps, then serve it with the
 Jenga-managed engine. Run: PYTHONPATH=src python examples/quickstart.py"""
 from repro.configs import ARCHS, reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.models.tp import single_device_dist
 from repro.serving import Engine, EngineConfig, Request, SamplingParams
@@ -8,6 +9,7 @@ from repro.training import AdamWConfig, SyntheticLM, Trainer, TrainerConfig
 
 
 def main():
+    use_compile_cache()
     cfg = reduced(ARCHS["granite-3-2b"])
     model = build_model(cfg, single_device_dist())
 

@@ -4,6 +4,7 @@ vs PagedAttention-baseline peak pool usage.
 Run: PYTHONPATH=src python examples/serve_heterogeneous.py"""
 from repro.configs import ARCHS, reduced
 from repro.core.request import MMItem
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.models.tp import single_device_dist
 from repro.serving import Engine, EngineConfig, Request, SamplingParams
@@ -25,6 +26,7 @@ def serve(arch: str, mode: str):
 
 
 def main():
+    use_compile_cache()
     for arch in ("h2o-danube-3-4b", "zamba2-1.2b", "whisper-tiny"):
         j = serve(arch, "jenga")
         p = serve(arch, "paged-baseline")

@@ -4,12 +4,14 @@ Run: PYTHONPATH=src python examples/train_hybrid.py"""
 import numpy as np
 
 from repro.configs import ARCHS, reduced
+from repro.launch.compile_cache import use_compile_cache
 from repro.models.registry import build_model
 from repro.models.tp import single_device_dist
 from repro.training import AdamWConfig, SyntheticLM, Trainer, TrainerConfig
 
 
 def main():
+    use_compile_cache()
     cfg = reduced(ARCHS["zamba2-1.2b"])
     model = build_model(cfg, single_device_dist())
     trainer = Trainer(model, AdamWConfig(lr=5e-3, warmup_steps=10,

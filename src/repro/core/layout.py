@@ -12,15 +12,55 @@ TP note: the buffer is allocated per model-parallel shard with the KV-head
 dim already divided, so the geometry below is constructed from *local* head
 counts; exec page ids are identical on every shard (the allocator is
 host-side and global).
+
+Device shape: the buffer lives as ``(rows, lanes)`` (see ``buffer_lanes``),
+never as a flat vector. TPU tiles the two minor dimensions of an array; a
+flat ``(1, units)`` bf16 array gets (2, 128) tiles whose padded row doubles
+its bytes, and a reshape to small-minor views such as ``(..., kv_heads,
+head_dim)`` is a relayout copy of the whole pool. Rows of 128 lanes tile
+without padding and every ``(..., rows, 128)`` reshape of them is free, so
+the serve path addresses K/V by rows (``attention.gather_pages`` /
+``write_token_kv``).
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Tuple
+import math
+from typing import Dict, Iterable, Tuple
 
 import jax.numpy as jnp
 
 from .spec import KVCacheSpec, PageGeometry
+
+
+LANES = 128                   # TPU vector lanes: the buffer's minor dimension
+ATTENTION_KINDS = ("full_attn", "swa", "cross_attn")
+
+
+def buffer_lanes(specs: Iterable[KVCacheSpec], total_units: int) -> int:
+    """Minor dimension of the unified buffer's ``(rows, lanes)`` shape: the
+    largest divisor of 128 that divides the pool and every attention type's
+    per-token K (or V) row, so each token's K/V and each page-layer block
+    is a whole number of rows. 128 for every published-width config whose
+    ``kv_heads * head_dim`` is a multiple of 128."""
+    lanes = math.gcd(LANES, total_units)
+    for spec in specs:
+        if spec.kind in ATTENTION_KINDS:
+            lanes = math.gcd(lanes, spec.units_per_token_per_layer // 2)
+    return lanes
+
+
+def buffer_shape(specs: Iterable[KVCacheSpec], total_units: int
+                 ) -> Tuple[int, ...]:
+    """Device shape of one unified buffer: leading (data, model) shard
+    dims, then ``(rows, lanes)``."""
+    lanes = buffer_lanes(specs, total_units)
+    return (1, 1, total_units // lanes, lanes)
+
+
+def as_rows(buffer, specs: Iterable[KVCacheSpec]):
+    """The unified buffer (any shape) as its ``(rows, lanes)`` device view."""
+    return buffer.reshape(-1, buffer_lanes(specs, buffer.size))
 
 
 @dataclasses.dataclass(frozen=True)

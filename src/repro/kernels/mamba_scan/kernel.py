@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams as _CompilerParams
 
 
 def _kernel(x_ref, b_ref, c_ref, dt_ref, alog_ref, y_ref, s_ref,
@@ -75,7 +74,7 @@ def mamba_chunk_scan(x, bm, cm, dt, a_log, *, chunk=64, interpret=True):
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
         out_shape=jax.ShapeDtypeStruct((b, h, nc, chunk, p), jnp.float32),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(xg, bg, cg, dtg, a_log.astype(jnp.float32))
     return jnp.moveaxis(out, 1, 3).reshape(b, t, h, p)

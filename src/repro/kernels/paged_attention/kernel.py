@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams as _CompilerParams
 
 NEG_INF = -1e30
 
@@ -106,7 +105,7 @@ def paged_decode_attention(q, kv_view, tables, page_pos, positions, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvl, g, d), q.dtype),
         interpret=interpret,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(tables_safe, page_pos.astype(jnp.int32), positions.astype(jnp.int32),
       q, kv_view)

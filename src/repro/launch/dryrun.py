@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_XLA_EXTRA", "") +
-                           " --xla_force_host_platform_device_count=512")
-os.environ["JAX_ENABLE_X64"] = "true"  # KV pools exceed 2^31 units
 """Multi-pod dry-run driver (deliverable e).
 
 For one (arch x shape x mesh) cell: build the production mesh, lower +
@@ -16,6 +12,7 @@ Usage:
 """
 import argparse
 import json
+import os
 import re
 import sys
 import time
@@ -221,6 +218,13 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool) -> dict:
 
 
 def main():
+    # process setup for the dry run only, never at import: 512 host
+    # devices stand in for the production mesh (read when the backend
+    # starts), and KV pools exceed 2^31 units
+    os.environ["XLA_FLAGS"] = (os.environ.get("_REPRO_XLA_EXTRA", "") +
+                               " --xla_force_host_platform_device_count=512")
+    import jax
+    jax.config.update("jax_enable_x64", True)
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

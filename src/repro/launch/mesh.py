@@ -10,8 +10,8 @@ import jax
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    from ..models.tp import make_mesh_auto
-    return make_mesh_auto(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def production_dist(*, multi_pod: bool = False, sp: bool = False):

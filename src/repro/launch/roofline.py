@@ -19,9 +19,22 @@ import glob
 import json
 import os
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
+# Published per-chip peaks (bf16 FLOP/s, HBM bytes/s), keyed by JAX's
+# ``Device.device_kind``. Source: Google Cloud TPU documentation, "TPU v5e".
+PEAKS = {"TPU v5 lite": (197e12, 819e9)}
+# The dry-run reports (this module's main) model a v5e pod.
+DRYRUN_KIND = "TPU v5 lite"
 LINK_BW = 50e9
+
+
+def chip_peaks(device_kind: str):
+    """(peak bf16 FLOP/s, HBM bytes/s) of one chip of ``device_kind``. A
+    kind missing from ``PEAKS`` is an error, never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; add them to PEAKS") from None
 
 
 def count_params(cfg) -> dict:
@@ -163,6 +176,7 @@ def analytic_terms(cfg, shape, devices):
 
 def load(dirname):
     from ..configs import ARCHS, SHAPES_BY_NAME
+    peak_flops, hbm_bw = chip_peaks(DRYRUN_KIND)
     rows = []
     for f in sorted(glob.glob(os.path.join(dirname, "*.json"))):
         if f.endswith("roofline.json"):
@@ -181,8 +195,8 @@ def load(dirname):
         coll = sum(v["bytes"] for v in r["collectives"].values())
         lf = loop_factor(cfg, shape)
         a_flops, a_bytes = analytic_terms(cfg, shape, r["devices"])
-        t_c = a_flops / PEAK_FLOPS
-        t_m = a_bytes / HBM_BW
+        t_c = a_flops / peak_flops
+        t_m = a_bytes / hbm_bw
         t_x = coll * lf / LINK_BW
         dominant = max((("compute", t_c), ("memory", t_m),
                         ("collective", t_x)), key=lambda kv: kv[1])[0]
@@ -191,7 +205,7 @@ def load(dirname):
         useful = mf / max(1.0, r.get("flops_per_device", 1) * lf)
         bound = max(t_c, t_m, t_x)
         # roofline fraction: useful model FLOPs at peak vs the bound term
-        frac = (mf / PEAK_FLOPS) / bound if bound else 0.0
+        frac = (mf / peak_flops) / bound if bound else 0.0
         pool = 2 * r.get("buffer_units_per_device", 0)
         temp = r.get("temp_size_in_bytes", 0)
         copies = int(temp // pool) if pool else 0
@@ -200,8 +214,8 @@ def load(dirname):
             "arch": r["arch"], "shape": r["shape"], "mesh": r["mesh"],
             "status": "ok", "devices": r["devices"],
             "t_compute_s": t_c, "t_memory_s": t_m, "t_collective_s": t_x,
-            "hlo_t_compute_s": r.get("flops_per_device", 0) / PEAK_FLOPS,
-            "hlo_t_memory_s": r.get("bytes_accessed_per_device", 0) / HBM_BW,
+            "hlo_t_compute_s": r.get("flops_per_device", 0) / peak_flops,
+            "hlo_t_memory_s": r.get("bytes_accessed_per_device", 0) / hbm_bw,
             "loop_factor": lf,
             "dominant": dominant, "model_flops_per_dev": mf,
             "hlo_flops_per_dev": r.get("flops_per_device", 0),

@@ -195,12 +195,14 @@ def view_offset(view_shape, eid, layer, sel, slot):
     return ((((eid * nl + layer) * 2 + sel) * tpp) + slot) * kvl * d
 
 
-def gather_pages(view, tables, layer):
-    """view: (VP, L, 2, TPP, KVL, D); tables: (B, P) int32 (entries < 0 are
-    invalid pads/frees). Returns k, v: (B, P*TPP, KVL, D).
+def gather_pages(buf, view_shape, tables, layer):
+    """buf: the unified buffer as ``(rows, lanes)`` (``layout.as_rows``);
+    view_shape: (VP, L, 2, TPP, KVL, D); tables: (B, P) int32 (entries < 0
+    are invalid pads/frees). Returns k, v: (B, P*TPP, KVL, D).
 
-    Layer is sliced BEFORE the page gather so the gather only moves this
-    layer's bytes (the slice itself is free).
+    One gather of whole (page, layer) blocks, indexed ``page * L + layer``
+    on a row view: only this layer's bytes of the listed pages move, and
+    the view is a free reshape of the rows (no relayout of the pool).
 
     Invalid entries are ZEROED, not merely masked downstream: the clamped
     gather would otherwise read arbitrary units of the unified buffer —
@@ -210,39 +212,43 @@ def gather_pages(view, tables, layer):
     NaN*0 == NaN in the rescale). VALID pages are safe without an isnan
     scrub because the runner zero-initialises every freshly allocated page
     (ModelRunner.zero_pages) before its first dispatch."""
-    lview = jax.lax.dynamic_index_in_dim(view, layer, axis=1, keepdims=False)
-    pages = jnp.take(lview, jnp.maximum(tables, 0), axis=0)  # (B,P,2,TPP,KVL,D)
-    valid = (tables >= 0)[:, :, None, None, None, None]
-    pages = jnp.where(valid, pages, 0)
+    vp, nl, _, tpp, kvl, d = view_shape
+    lanes = buf.shape[-1]
+    blk = 2 * tpp * kvl * d // lanes                  # rows per page-layer
+    rows = buf.reshape(vp * nl, blk, lanes)
+    pages = jnp.take(rows, jnp.maximum(tables, 0) * nl + layer, axis=0)
+    pages = jnp.where((tables >= 0)[:, :, None, None], pages, 0)
+    b, p = tables.shape
+    pages = pages.reshape(b, p, 2, tpp, kvl, d)
     k = pages[:, :, 0]
     v = pages[:, :, 1]
-    b, p, tpp, kvl, d = k.shape
     return k.reshape(b, p * tpp, kvl, d), v.reshape(b, p * tpp, kvl, d)
 
 
 def write_token_kv(buf, view_shape, layer, eids, slots, k_new, v_new):
     """Write T new tokens per sequence into their pages.
 
-    buf: flat (U,) unified buffer (the scan carry); view_shape:
-    (VP, L, 2, TPP, KVL, D); eids: (B, T) exec page id per new token (<0 =
-    drop, e.g. non-owner shard in the replica-split); slots: (B, T) slot
-    within page; k_new/v_new: (B, T, KVL, D). Returns the updated flat buf."""
-    if _WRITE_MODE[0] == "scatter":
-        view = buf.reshape(view_shape)
-        vp, nl, _, tpp, kvl, d = view_shape
-        b, t = eids.shape
-        eids_f = jnp.where(eids < 0, vp, eids).reshape(-1)    # OOB -> dropped
-        slot_f = slots.reshape(-1)
-        kf = k_new.reshape(b * t, kvl, d).astype(view.dtype)
-        vf = v_new.reshape(b * t, kvl, d).astype(view.dtype)
-        layer_f = jnp.full((b * t,), layer, jnp.int32)
-        view = view.at[eids_f, layer_f, 0, slot_f].set(
-            kf, mode="drop", unique_indices=False)
-        view = view.at[eids_f, layer_f, 1, slot_f].set(
-            vf, mode="drop", unique_indices=False)
-        return view.reshape(buf.shape)
-    return _write_token_kv_dus(buf, view_shape, layer, eids, slots,
-                               k_new, v_new)
+    buf: the unified buffer as ``(rows, lanes)`` (the scan carry);
+    view_shape: (VP, L, 2, TPP, KVL, D); eids: (B, T) exec page id per new
+    token (<0 = drop, e.g. non-owner shard in the replica-split); slots:
+    (B, T) slot within page; k_new/v_new: (B, T, KVL, D). Each token's K
+    and V rows are scattered as whole buffer rows. Returns the updated
+    buf."""
+    if _WRITE_MODE[0] == "dus":
+        return _write_token_kv_dus(buf, view_shape, layer, eids, slots,
+                                   k_new, v_new)
+    vp, nl, _, tpp, kvl, d = view_shape
+    lanes = buf.shape[-1]
+    tr = kvl * d // lanes                             # rows per token
+    b, t = eids.shape
+    k_row = ((jnp.maximum(eids, 0) * nl + layer) * 2 * tpp + slots) * tr
+    k_row = jnp.where(eids >= 0, k_row, buf.shape[0])  # OOB -> dropped
+    v_row = jnp.where(eids >= 0, k_row + tpp * tr, buf.shape[0])
+    sub = jnp.arange(tr)
+    idx = jnp.stack([k_row, v_row], axis=-1)[..., None] + sub  # (B,T,2,tr)
+    upd = jnp.stack([k_new, v_new], axis=2).astype(buf.dtype)
+    return buf.at[idx.reshape(-1)].set(
+        upd.reshape(-1, lanes), mode="drop", unique_indices=False)
 
 
 def _write_token_kv_dus(buf, view_shape, layer, eids, slots, k_new, v_new):
@@ -256,6 +262,8 @@ def _write_token_kv_dus(buf, view_shape, layer, eids, slots, k_new, v_new):
     Decode (T==1): one dus row per sequence. Prefill (T>1): fori_loop over
     (seq, page) writing whole page-layer slices — requires the chunk to start
     page-aligned (guaranteed by the runner in dus mode)."""
+    shape = buf.shape
+    buf = buf.reshape(-1)
     vp, nl, _, tpp, kvl, d = view_shape
     b, t = eids.shape
     row = kvl * d
@@ -272,7 +280,7 @@ def _write_token_kv_dus(buf, view_shape, layer, eids, slots, k_new, v_new):
                 off = jnp.where(eid >= 0, off, total - row)   # -> scratch
                 buf = jax.lax.dynamic_update_slice(
                     buf, data[bi, 0].reshape(row), (off,))
-        return buf
+        return buf.reshape(shape)
     # prefill: page-granular writes
     npg = -(-t // tpp)
     pad = npg * tpp - t
@@ -298,7 +306,7 @@ def _write_token_kv_dus(buf, view_shape, layer, eids, slots, k_new, v_new):
                 (off,))
         return buf
 
-    return jax.lax.fori_loop(0, b * npg, body, buf)
+    return jax.lax.fori_loop(0, b * npg, body, buf).reshape(shape)
 
 
 def bf16_pair_to_f32(x):
@@ -338,10 +346,12 @@ def write_state(buf, view_shape, layer, eids, state):
         view = view.at[eids_s, layer_f].set(
             data, mode="drop", unique_indices=False)
         return view.reshape(buf.shape)
+    shape = buf.shape
+    buf = buf.reshape(-1)
     total = buf.shape[0]
     for bi in range(eids.shape[0]):
         eid = eids[bi]
         off = (jnp.maximum(eid, 0).astype(jnp.int64) * nl + layer) * u2
         off = jnp.where(eid >= 0, off, total - u2)            # -> scratch
         buf = jax.lax.dynamic_update_slice(buf, data[bi], (off,))
-    return buf
+    return buf.reshape(shape)

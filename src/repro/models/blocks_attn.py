@@ -11,7 +11,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from ..kernels.flash_attention.kernel import flash_attention_varlen_tpu
+from ..kernels.flash_attention.kernel import (block_intervals,
+                                              flash_attention_varlen_tpu)
 from . import attention as A
 from .common import dense, rms_norm
 from .rotary import apply_mrope, apply_rope
@@ -34,11 +35,13 @@ def sparse_blocks(t: int, s: int) -> tuple:
     are tight and non-overlapping (q block, kv block) pairs can be
     skipped. Aim for ~4 query blocks and ~16 KV blocks so skipping has
     granularity to work with at serving sizes, clamped to the MXU-friendly
-    maxima (128 x 512) at scale and TPU-tile minima (8 x 64) below.
+    maxima (128 x 512) at scale and to the TPU tile (8 x 128) below: the
+    varlen kernel's per-block metadata tiles are (blk_q, 128) and
+    (8, blk_k), which the TPU compiler accepts only at these multiples.
     ``ModelRunner._attn_block_stats`` mirrors this sizing on the host for
     the StepMetrics work counters — keep the two in sync."""
     return (max(8, min(Q_BLOCK, _pow2_floor(t // 4))),
-            max(64, min(KV_BLOCK, _pow2_floor(s // 16))))
+            max(128, min(KV_BLOCK, _pow2_floor(s // 16))))
 
 
 # ---------------------------------------------------------------- attention
@@ -104,8 +107,7 @@ def attn_gather(buf, view_shape, tables, page_pos, layer, page_seg=None):
     segments' pages share one flat table row); None for the padded
     row-per-sequence layout. Returns (k, v, slot_pos, slot_seg) with
     slot_seg None when page_seg is None."""
-    view = buf.reshape(view_shape)
-    k_all, v_all = A.gather_pages(view, tables, layer)
+    k_all, v_all = A.gather_pages(buf, view_shape, tables, layer)
     b, p = tables.shape
     tpp = view_shape[3]
     s = k_all.shape[1]
@@ -337,11 +339,8 @@ def _prefill_flash_sparse(qf, kb, vb, pb, sb, q_seg, q_pos, chunk_start, *,
     kbr, vbr, pbr, sbr = kb[0], vb[0], pb[0], sb[0]
 
     # per-block segment-id intervals (pads excluded: q pads -1, kv -2)
-    big = jnp.int32(1 << 30)
-    k_lo = jnp.min(jnp.where(sbr >= 0, sbr, big), axis=1)      # (nblk,)
-    k_hi = jnp.max(jnp.where(sbr >= 0, sbr, -big), axis=1)
-    q_lo = jnp.min(jnp.where(qsb >= 0, qsb, big), axis=1)      # (nqb,)
-    q_hi = jnp.max(jnp.where(qsb >= 0, qsb, -big), axis=1)
+    k_lo, k_hi = block_intervals(sbr, block)                    # (nblk,)
+    q_lo, q_hi = block_intervals(qsb, q_block)                  # (nqb,)
 
     def qblock(_, qx):
         qfb_i, qsb_i, qpb_i, csb_i, qlo_i, qhi_i = qx
@@ -460,38 +459,6 @@ def packed_cross_attn_kernel(q, k_all, v_all, slot_pos, slot_seg, seg_ids,
         slot_pos[0], blk_q=blk_q, blk_k=blk_k,
         interpret=jax.default_backend() != "tpu")
     return _bh_out(out, kvl, g)
-
-
-def cross_attn_cached(p, x, view, dist: Dist, *, layer, kv_local, head_dim,
-                      tables, enc_lens, norm_eps=1e-5):
-    """Cross-attention reading encoder KV from cross-attn pages (read-only;
-    caller passes the reshape view)."""
-    b, t, _ = x.shape
-    tpp = view.shape[3]
-    xn = rms_norm(x, p["attn_norm"], norm_eps)
-    q = dense(xn, p["q"]).reshape(b, t, -1, head_dim)
-    q = A.group_q(q, kv_local)
-    k_all, v_all = A.gather_pages(view, tables, layer)
-    s = k_all.shape[1]
-    slot_idx = jnp.arange(s)[None]                             # (1, S)
-    mask = jnp.broadcast_to(slot_idx < enc_lens[:, None], (b, s))
-    mask = jnp.broadcast_to(mask[:, None, :], (b, t, s))
-    o, m, l = A.attend_tokens(q, k_all, v_all, mask)
-    out = A.finalize_softmax(o, l).reshape(b, t, -1).astype(x.dtype)
-    y = dense(out, p["o"])
-    return x + psum_tp(y, dist)
-
-
-def write_cross_kv(p, enc_out, buf, view_shape, *, layer, kv_local,
-                   head_dim, write_eids):
-    """Project encoder output and write K/V into cross-attn pages.
-    enc_out: (B, S_enc, d); write_eids: (B, S_enc)."""
-    b, s, _ = enc_out.shape
-    tpp = view_shape[3]
-    k = dense(enc_out, p["k"]).reshape(b, s, kv_local, head_dim)
-    v = dense(enc_out, p["v"]).reshape(b, s, kv_local, head_dim)
-    slots = jnp.broadcast_to(jnp.arange(s)[None] % tpp, (b, s))
-    return A.write_token_kv(buf, view_shape, layer, write_eids, slots, k, v)
 
 
 def cross_attn_train(p, x, enc_out, dist: Dist, *, kv_local, head_dim,

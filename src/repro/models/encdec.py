@@ -24,7 +24,7 @@ from .params import PD
 from .rotary import sinusoidal_positions
 from .tp import (embed_lookup, expand_gqa_kv, expand_gqa_o, expand_gqa_q,
                  logits_local, mask_pad_vocab, psum_dp, psum_tp, replica_info,
-                 shard_map, sharded_softmax_xent)
+                 sharded_softmax_xent)
 
 MAX_DEC_POS = 32768 + 8
 
@@ -168,7 +168,7 @@ class EncDecLM(DecoderLM):
     def train_loss(self, params, tokens, targets, *, enc_embeds=None, **kw):
         dist = self.dist
         dp = _dp_spec(dist)
-        fn = shard_map(
+        fn = jax.shard_map(
             self._train_body_ed, mesh=dist.mesh,
             in_specs=(self.specs(), P(dp), P(dp), P(dp)),
             out_specs=P(), check_vma=False)
@@ -204,7 +204,6 @@ class EncDecLM(DecoderLM):
         cfg, dist, ri = self.cfg, self.dist, self.ri
         eps = cfg.norm_eps
         params = self._squeeze_params(params)
-        buffer = buffer.reshape(buffer.shape[-1])
         views = self._layer_views(buffer)
         sq = lambda a: jnp.squeeze(a, axis=(0, 1))
         tables_sa = sq(batch.tables["full_attn"])
@@ -270,8 +269,8 @@ class EncDecLM(DecoderLM):
                     buf, views["cross_attn"], tables_ca, page_pos_ca,
                     layer, page_seg_ca)
             else:
-                cview = buf.reshape(views["cross_attn"])
-                kc, vc = A.gather_pages(cview, tables_ca, layer)
+                kc, vc = A.gather_pages(buf, views["cross_attn"], tables_ca,
+                                        layer)
             # --- causal self attention (paged, fresh KV merged from registers)
             xn = layer_norm(x, ps["ln_w"], ps["ln_b"], eps)
             q = dense(xn, ps["q"], ps["q_bias"]).reshape(b, t, -1, cfg.head_dim)
@@ -378,4 +377,4 @@ class EncDecLM(DecoderLM):
             x = x[:, -1:]
         logits = logits_local(x, params["embed"])[:, 0]
         logits = mask_pad_vocab(logits, cfg.vocab_size, dist)
-        return logits, buffer.reshape(1, 1, -1)
+        return logits, buffer

@@ -179,7 +179,6 @@ class HybridLM(DecoderLM):
                     attention_impl="ref"):
         cfg, dist = self.cfg, self.dist
         params = self._squeeze_params(params)
-        buffer = buffer.reshape(buffer.shape[-1])
         tokens = batch.tokens
         b, t = tokens.shape
         positions = batch.positions
@@ -280,4 +279,4 @@ class HybridLM(DecoderLM):
             x = x[:, -1:]
         logits = logits_local(x, self._unembed(params))[:, 0]
         logits = mask_pad_vocab(logits, cfg.vocab_size, dist)
-        return logits, buffer.reshape(1, 1, -1)
+        return logits, buffer

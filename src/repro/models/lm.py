@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..configs.base import ModelConfig
+from ..core.layout import as_rows
 from ..core.spec import KVCacheSpec, attention_spec
 from . import attention as A
 from . import blocks_attn as BA
@@ -23,8 +24,7 @@ from .params import PD, init_params, param_specs, param_struct
 from .rotary import mrope_positions as _mrope3
 from .tp import (Dist, embed_lookup, expand_gqa_kv, expand_gqa_o,
                  expand_gqa_q, gather_logits, logits_local, mask_pad_vocab,
-                 psum_dp, psum_tp, replica_info, shard_map,
-                 sharded_softmax_xent)
+                 psum_dp, psum_tp, replica_info, sharded_softmax_xent)
 
 
 @dataclasses.dataclass
@@ -304,7 +304,7 @@ class DecoderLM:
         if cfg.family == "vlm" and mm_embeds is not None:
             extra_specs = [P(dp), P(dp), P(None, dp)]
             args += [mm_embeds, mm_mask, mrope_pos]
-        fn = shard_map(
+        fn = jax.shard_map(
             partial(self._train_body, has_mm=bool(extra_specs)),
             mesh=dist.mesh,
             in_specs=tuple(in_specs) + tuple(extra_specs),
@@ -408,9 +408,18 @@ class DecoderLM:
         buf_spec = P(shard_dim_spec, "model")
         out_logit_spec = (P(None, "model") if (sp or packed)
                           else P(dp, "model"))
-        fn = shard_map(
-            partial(self._serve_body, prefill=prefill,
-                    attention_impl=attention_impl),
+        body = partial(self._serve_body, prefill=prefill,
+                       attention_impl=attention_impl)
+        kv_specs = self.kv_specs()
+
+        def on_rows(params, buf, batch):
+            # every serve body reads and writes the local unified buffer
+            # as its (rows, lanes) view (core.layout) and returns that view
+            logits, rows = body(params, as_rows(buf, kv_specs), batch)
+            return logits, rows.reshape(buf.shape)
+
+        fn = jax.shard_map(
+            on_rows,
             mesh=dist.mesh,
             in_specs=(self.specs(), buf_spec, batch_specs),
             out_specs=(out_logit_spec, buf_spec),
@@ -418,12 +427,12 @@ class DecoderLM:
         )
         return fn(params, buffer, batch)
 
-    def _layer_views(self, buffer_flat):
+    def _layer_views(self, buffer):
         """Per-type reshape views of the unified buffer (paper Fig. 7c):
         type t sees (total_units // S_t, num_layers_t, *page_shape)."""
         specs = self.kv_specs()
         shapes = self.page_shapes()
-        total = buffer_flat.shape[-1]
+        total = buffer.size
         views = {}
         for s in specs:
             assert total % s.page_units == 0, (
@@ -437,7 +446,6 @@ class DecoderLM:
                     attention_impl="ref"):
         cfg, dist = self.cfg, self.dist
         params = self._squeeze_params(params)
-        buffer = buffer.reshape(buffer.shape[-1])          # local flat units
         tokens = batch.tokens
         b, t = tokens.shape
         positions = batch.positions
@@ -516,4 +524,4 @@ class DecoderLM:
             x = x[:, -1:]
         logits = logits_local(x, self._unembed(params))[:, 0]  # (B, V_loc)
         logits = mask_pad_vocab(logits, cfg.vocab_size, dist)
-        return logits, buffer.reshape(1, 1, -1)
+        return logits, buffer

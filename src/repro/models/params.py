@@ -12,6 +12,20 @@ from jax.sharding import PartitionSpec as P
 from .common import PARAM_DTYPE
 
 
+# Leaves (by their dict key) that every use casts to the compute dtype
+# (``common.DTYPE``) before a matmul or an embedding lookup, so storing
+# them in bf16 changes no result. Every other leaf — norms, biases, SSM
+# decays and skip weights, RWKV mixing coefficients, bonus and decay LoRA
+# output, the MoE router — is read in fp32.
+MATMUL_WEIGHTS = frozenset({
+    "q", "k", "v", "o", "gate", "up", "down", "embed", "unembed", "dec_pos",
+    "moe_gate", "moe_up", "moe_down", "w1", "w2",
+    "w_z", "w_x", "w_B", "w_C", "w_dt", "w_out",
+    "w_r", "w_k", "w_v", "w_g", "w_o", "w_lora_a",
+    "cm_wk", "cm_wr", "cm_wv",
+})
+
+
 @dataclasses.dataclass
 class PD:
     """One parameter definition."""

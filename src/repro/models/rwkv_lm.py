@@ -128,7 +128,6 @@ class RWKVLM(DecoderLM):
         # unused: RWKV has no attention layers to dispatch
         cfg, dist = self.cfg, self.dist
         params = self._squeeze_params(params)
-        buffer = buffer.reshape(buffer.shape[-1])
         x = embed_lookup(batch.tokens, params["embed"], dist)
         views = self._layer_views(buffer)
         state_eids = jnp.squeeze(batch.state_eids["rwkv"], axis=0)
@@ -177,4 +176,4 @@ class RWKVLM(DecoderLM):
             x = x[:, -1:]
         logits = logits_local(x, self._unembed(params))[:, 0]
         logits = mask_pad_vocab(logits, cfg.vocab_size, dist)
-        return logits, buffer.reshape(1, 1, -1)
+        return logits, buffer

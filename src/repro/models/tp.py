@@ -21,7 +21,7 @@ from typing import Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 from .common import PARAM_DTYPE, gqa_tp_layout
 
@@ -52,37 +52,10 @@ class Dist:
         return NamedSharding(self.mesh, P(*spec))
 
 
-# ------------------------------------------------------- version compat
-# The repo targets recent JAX (jax.shard_map / AxisType / check_vma) but must
-# run on older releases where these live under jax.experimental (shard_map
-# with check_rep) and meshes carry no axis_types. Feature-detect once here;
-# every model file imports `shard_map` / `make_mesh_auto` from this module.
-_HAS_AXIS_TYPE = hasattr(jax.sharding, "AxisType")
-
-
-def make_mesh_auto(shape, names, devices=None):
-    if _HAS_AXIS_TYPE:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, names,
-                             axis_types=(AxisType.Auto,) * len(names),
-                             devices=devices)
-    return jax.make_mesh(shape, names, devices=devices)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """jax.shard_map on new JAX; jax.experimental.shard_map fallback (where
-    the kwarg disabling replication checking is spelled ``check_rep``)."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def single_device_dist() -> Dist:
-    mesh = make_mesh_auto((1, 1), ("data", "model"),
-                          devices=jax.devices()[:1])
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2,
+                         devices=jax.devices()[:1])
     return Dist(mesh=mesh)
 
 

@@ -9,8 +9,9 @@ the step flips from bandwidth-bound to compute-bound around
     T* = PEAK_FLOPS * (2 bytes * n_total) / (HBM_BW * 2 FLOPs * n_active)
        = (PEAK_FLOPS / HBM_BW) * n_total / n_active
 
-tokens (~240 for a dense model on the modeled chip; higher for MoE,
-whose total/active ratio > 1). Below T* extra tokens in a step are
+tokens (~240 for a dense model on a v5e; higher for MoE, whose
+total/active ratio > 1). The peaks are those of the chip the engine runs
+on (``launch.roofline.PEAKS``, keyed by ``device_kind``). Below T* extra tokens in a step are
 nearly free — the budget should at least reach it. A fraction of the
 budget is reserved for decodes (``max_prefill_tokens_per_step``), the
 scheduler's latency knob.
@@ -29,7 +30,7 @@ import dataclasses
 from collections import deque
 from typing import Deque, Optional
 
-from ..launch.roofline import HBM_BW, PEAK_FLOPS, count_params
+from ..launch.roofline import chip_peaks, count_params
 
 QUANTUM = 16          # packed-stream token bucket quantum (_tok_bucket)
 MIN_BUDGET = 32
@@ -47,11 +48,13 @@ def shard_pool_bytes(total_bytes: int, num_shards: int) -> int:
     return max(1, total_bytes // max(1, num_shards))
 
 
-def roofline_token_budget(model_cfg) -> int:
+def roofline_token_budget(model_cfg, device_kind: str) -> int:
     """Compute/memory balance point T* of one serving step for this model
-    config, rounded to the packed-stream bucket quantum."""
+    config on one ``device_kind`` chip, rounded to the packed-stream bucket
+    quantum."""
+    peak_flops, hbm_bw = chip_peaks(device_kind)
     n = count_params(model_cfg)
-    t_star = (PEAK_FLOPS / HBM_BW) * n["total"] / max(1, n["active"])
+    t_star = (peak_flops / hbm_bw) * n["total"] / max(1, n["active"])
     return max(MIN_BUDGET, min(MAX_BUDGET, _round_q(t_star)))
 
 
@@ -62,6 +65,7 @@ class BudgetAutotuner:
     ``prefill_cap`` whenever ``observe`` returns True."""
 
     model_cfg: object
+    device_kind: str                 # chip whose roofline seeds the budget
     decode_reserve: float = 0.25     # budget fraction kept for decodes
     window: int = 16                 # steps per observation window
     # Data-parallel shard budgets: the roofline balance point T* is PER
@@ -76,7 +80,7 @@ class BudgetAutotuner:
     prefill_cap: int = dataclasses.field(init=False)
 
     def __post_init__(self):
-        self.budget = roofline_token_budget(self.model_cfg)
+        self.budget = roofline_token_budget(self.model_cfg, self.device_kind)
         self.prefill_cap = max(
             QUANTUM, _round_q(self.budget * (1.0 - self.decode_reserve)))
         self.window = int(self.window * max(1, self.num_shards))

@@ -47,7 +47,8 @@ from typing import Dict, List, Optional, Sequence
 
 from ..core.request import SequenceState
 from .autotune import BudgetAutotuner, shard_pool_bytes
-from .engine import Engine, EngineConfig, ShardHealth, StepMetrics
+from .engine import (Engine, EngineConfig, ShardHealth, StepMetrics,
+                     device_kind, serving_params)
 from .request import Request, Status
 from .router import Router, RouterConfig
 
@@ -109,7 +110,9 @@ class DPEngine:
         if split_pool and n > 1:
             shard_cfg = dataclasses.replace(
                 cfg, kv_pool_bytes=shard_pool_bytes(cfg.kv_pool_bytes, n))
-        params = params if params is not None else model.init(seed)
+        # one served copy shared by every shard
+        params = serving_params(
+            params if params is not None else model.init(seed))
         if roles is not None:
             assert len(roles) == n, (len(roles), n)
             assert all(r in ("both", "prefill", "decode") for r in roles), \
@@ -121,7 +124,8 @@ class DPEngine:
                 cfg_i = dataclasses.replace(shard_cfg, role=roles[sid])
             eng = Engine(model, cfg_i, params=params, seed=seed)
             if shard_cfg.autotune_budgets:
-                eng.autotuner = BudgetAutotuner(model.cfg, num_shards=n)
+                eng.autotuner = BudgetAutotuner(
+                    model.cfg, device_kind(model), num_shards=n)
                 eng.scheduler.set_budgets(eng.autotuner.budget,
                                           eng.autotuner.prefill_cap)
             self.shards.append(EngineShard(sid, eng))

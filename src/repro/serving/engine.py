@@ -65,9 +65,12 @@ import time
 from collections import deque
 from typing import Callable, Deque, Dict, List, Optional, Sequence, Set, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from ..core.manager import JengaKVCacheManager, StateCopyOp
+from ..models.params import MATMUL_WEIGHTS
 from .request import Request, SamplingParams, Status
 from .runner import ModelRunner
 from .sampler import TIE_EPS, greedy_token, host_sample, rid_hash
@@ -89,7 +92,8 @@ from .scheduler import ScheduledSeq, Scheduler, SchedulerConfig, StepPlan
 # flips (measured: 1e-3 flipped a dbrx 0.9e-3 near-tie, 3e-2 flipped on
 # danube's #3 candidate at gap ~3e-2), and the flip points move with the
 # band because earlier picks change the trajectory. Cross-layout greedy
-# comparisons therefore use the fork-aware checker in tests/conftest.py:
+# comparisons therefore use the fork-aware checker in
+# repro.analysis.greedy_equiv:
 # exact token equality until a divergence, which must itself be a
 # genuinely ambiguous decision (both candidates within TIE_FORK_TOL of
 # the max in BOTH modes' recorded fp32 rows — see
@@ -99,6 +103,39 @@ from .scheduler import ScheduledSeq, Scheduler, SchedulerConfig, StepPlan
 # (see serving.sampler._band_pick) and is bit-identical to the host form.
 TIE_EPS = TIE_EPS                  # re-exported; canonical home: sampler.py
 greedy_token = greedy_token
+
+
+# Weight matrices are served in bf16. Every use casts them to bf16 anyway
+# (models.common.dense), and fp32 masters leave no room for a KV pool on a
+# 16 GB chip: compiled for v5e, granite-3-2b's serve step with fp32 params
+# (10.1 GB) and a 2 GiB pool needs 15.99 GiB of the 15.75 GiB usable
+# (XLA hoists the per-layer weight casts out of the layer scan).
+SERVE_PARAM_DTYPE = jnp.bfloat16
+
+
+def serving_params(params):
+    """The params as served. Rule: an fp32 leaf whose dict key is in
+    ``models.params.MATMUL_WEIGHTS`` (a weight every use casts to bf16
+    before a matmul or embedding lookup) is stored as
+    ``SERVE_PARAM_DTYPE``; every other leaf keeps its dtype, so what the
+    model reads in fp32 stays fp32 and results do not change. Already-cast
+    trees pass through without a copy. Leaves are cast one at a time, so
+    an fp32 tree that nothing else holds is released leaf by leaf instead
+    of coexisting with its copy."""
+    flat, treedef = jax.tree_util.tree_flatten_with_path(params)
+    del params
+    keys = [getattr(path[-1], "key", None) for path, _ in flat]
+    leaves = [a for _, a in flat]
+    del flat
+    for i, key in enumerate(keys):
+        if key in MATMUL_WEIGHTS and leaves[i].dtype == jnp.float32:
+            leaves[i] = leaves[i].astype(SERVE_PARAM_DTYPE)
+    return jax.tree.unflatten(treedef, leaves)
+
+
+def device_kind(model) -> str:
+    """``device_kind`` of the chip a model's mesh places it on."""
+    return model.dist.mesh.devices.flat[0].device_kind
 
 
 def stub_modality_embed(mm_hash: int, offset: int, dim: int) -> np.ndarray:
@@ -295,14 +332,17 @@ class Engine:
         self.autotuner = None
         if cfg.autotune_budgets:
             from .autotune import BudgetAutotuner
-            self.autotuner = BudgetAutotuner(model.cfg)
+            self.autotuner = BudgetAutotuner(model.cfg, device_kind(model))
             self.scheduler.set_budgets(self.autotuner.budget,
                                        self.autotuner.prefill_cap)
         assert cfg.attention_impl in ("ref", "kernel"), cfg.attention_impl
+        # params before the pool: an engine-initialised fp32 tree is cast
+        # and released before the KV buffer claims device memory
+        self.params = serving_params(
+            params if params is not None else model.init(seed))
         self.runner = ModelRunner(model, self.mgr,
                                   stub_embed_fn=stub_modality_embed,
                                   attention_impl=cfg.attention_impl)
-        self.params = params if params is not None else model.init(seed)
         self.step_count = 0
         self.metrics: List[StepMetrics] = []
         self.sample_log: Dict[str, List[np.ndarray]] = {}

@@ -58,6 +58,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.layout import buffer_shape
 from ..core.manager import JengaKVCacheManager, StateCopyOp
 from ..core.request import SequenceState
 from ..core.spec import lcm as _lcm
@@ -82,6 +83,27 @@ def _tok_bucket(n: int) -> int:
     if n <= 16:
         return _pow2(n)
     return 16 * (-(-n // 16))
+
+
+def _page_rows(buf, size: int):
+    """``(pages, rows, lanes)`` view of the ``(..., rows, lanes)`` unified
+    buffer for whole-page copies — a free reshape on TPU, unlike a
+    ``(pages, size)`` view, whose tiles would straddle pages."""
+    return buf.reshape(-1, size // buf.shape[-1], buf.shape[-1])
+
+
+def _rows_aligned(buf, size: int) -> bool:
+    """Whole pages of ``size`` units tile the buffer in whole rows."""
+    return buf.size % size == 0 and size % buf.shape[-1] == 0
+
+
+def _unit_offset(page: int, size: int):
+    """int32 unit offset of a ``size``-unit page, for the flat per-page
+    copy paths (row-aligned batched paths index rows instead)."""
+    if (page + 1) * size > 2 ** 31:
+        raise OverflowError(f"page {page} of {size} units lies beyond "
+                            f"int32 unit offsets")
+    return jnp.int32(page * size)
 
 
 def _norm_items(items) -> List[Tuple[Request, int, int]]:
@@ -252,7 +274,8 @@ class ModelRunner:
         self.stub_embed_fn = stub_embed_fn
         big = _lcm([s.page_units for s in self.specs.values()])
         units = manager.geometry.total_units + big   # + scratch page
-        self.buffer = jnp.zeros((1, 1, units), jnp.bfloat16)
+        self.buffer = jnp.zeros(buffer_shape(manager.geometry.specs, units),
+                                jnp.bfloat16)
         # serve-step jit cache, shared across ALL runners of one model:
         # the cache pins the static ``prefill`` flag per bucket key, and
         # jax.jit itself retraces per input shape, so runners over pools of
@@ -869,10 +892,10 @@ class ModelRunner:
         by_type: Dict[str, List[StateCopyOp]] = {}
         for op in ops:
             by_type.setdefault(op.type_name, []).append(op)
-        total = self.buffer.shape[-1]
+        total = self.buffer.size
         for name, group in by_type.items():
             size = self.specs[name].page_units
-            if total % size:            # misaligned pool: per-op fallback
+            if not _rows_aligned(self.buffer, size):    # per-op fallback
                 for op in group:
                     self.copy_page(name, op.src_page, op.dst_page)
                 continue
@@ -885,7 +908,7 @@ class ModelRunner:
             fn = self._batch_copy_fns.get((size, cap))
             if fn is None:
                 def cp(buf, srcs, dsts, size_s):
-                    rows = buf.reshape(-1, size_s)
+                    rows = _page_rows(buf, size_s)
                     blk = jnp.take(rows, srcs, axis=0)
                     rows = rows.at[dsts].set(blk, mode="drop",
                                              unique_indices=False)
@@ -905,13 +928,13 @@ class ModelRunner:
         by_type: Dict[str, List[int]] = {}
         for name, eid in pages:
             by_type.setdefault(name, []).append(eid)
-        total = self.buffer.shape[-1]
+        total = self.buffer.size
         for name, eids in by_type.items():
             # manager spec table, not self.specs: with several models
             # sharing one pool (spec decode) a drain can surface pages of
             # types this runner's model does not own
             size = self.mgr.spec(name).page_units
-            if total % size:
+            if not _rows_aligned(self.buffer, size):
                 for eid in eids:
                     self.zero_page(name, eid)
                 continue
@@ -921,8 +944,8 @@ class ModelRunner:
             fn = self._batch_zero_fns.get((size, cap))
             if fn is None:
                 def z(buf, dsts, size_s, cap_s):
-                    rows = buf.reshape(-1, size_s)
-                    zero = jnp.zeros((cap_s, size_s), buf.dtype)
+                    rows = _page_rows(buf, size_s)
+                    zero = jnp.zeros((cap_s,) + rows.shape[1:], buf.dtype)
                     rows = rows.at[dsts].set(zero, mode="drop",
                                              unique_indices=False)
                     return rows.reshape(buf.shape)
@@ -941,7 +964,7 @@ class ModelRunner:
                 return flat.reshape(buf.shape)
             self._zero_fn = jax.jit(z, static_argnums=(2,),
                                     donate_argnums=(0,))
-        self.buffer = self._zero_fn(self.buffer, jnp.int32(eid * size), size)
+        self.buffer = self._zero_fn(self.buffer, _unit_offset(eid, size), size)
 
     def adopt_pages(self, src_runner: "ModelRunner",
                     pairs: Sequence[Tuple[str, int, int]]) -> None:
@@ -958,11 +981,11 @@ class ModelRunner:
         by_type: Dict[str, List[Tuple[int, int]]] = {}
         for name, src, dst in pairs:
             by_type.setdefault(name, []).append((src, dst))
-        s_total = src_runner.buffer.shape[-1]
-        d_total = self.buffer.shape[-1]
+        d_total = self.buffer.size
         for name, group in by_type.items():
             size = self.mgr.spec(name).page_units
-            if s_total % size or d_total % size:
+            if not (_rows_aligned(src_runner.buffer, size)
+                    and _rows_aligned(self.buffer, size)):
                 for src, dst in group:   # misaligned pool: per-op fallback
                     self._adopt_one(src_runner, name, src, dst)
                 continue
@@ -975,8 +998,9 @@ class ModelRunner:
             fn = self._xfer_fns.get((size, cap))
             if fn is None:
                 def xf(dst_buf, src_buf, srcs, dsts, size_s):
-                    blk = jnp.take(src_buf.reshape(-1, size_s), srcs, axis=0)
-                    rows = dst_buf.reshape(-1, size_s)
+                    blk = jnp.take(_page_rows(src_buf, size_s), srcs, axis=0)
+                    rows = _page_rows(dst_buf, size_s)
+                    blk = blk.reshape(blk.shape[:1] + rows.shape[1:])
                     rows = rows.at[dsts].set(blk, mode="drop",
                                              unique_indices=False)
                     return rows.reshape(dst_buf.shape)
@@ -1000,7 +1024,7 @@ class ModelRunner:
                                      donate_argnums=(0,))
         self.buffer = self._xfer1_fn(
             self.buffer, src_runner.buffer,
-            jnp.int32(src * size), jnp.int32(dst * size), size)
+            _unit_offset(src, size), _unit_offset(dst, size), size)
 
     def copy_page(self, type_name: str, src: int, dst: int) -> None:
         """Device copy of one whole small page (state checkpoint/restore)."""
@@ -1014,5 +1038,5 @@ class ModelRunner:
                 return flat.reshape(buf.shape)
             self._copy_fn = jax.jit(cp, static_argnums=(3,),
                                     donate_argnums=(0,))
-        self.buffer = self._copy_fn(
-            self.buffer, jnp.int32(src * size), jnp.int32(dst * size), size)
+        self.buffer = self._copy_fn(self.buffer, _unit_offset(src, size),
+                                    _unit_offset(dst, size), size)

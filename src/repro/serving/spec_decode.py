@@ -38,6 +38,7 @@ from typing import List, Optional, Tuple
 
 from ..core.manager import JengaKVCacheManager
 from ..core.request import SequenceState
+from .engine import serving_params
 from .request import Request
 from .runner import ModelRunner
 from .sampler import greedy_token
@@ -79,10 +80,10 @@ class SpecDecodeEngine:
         self.d_runner = ModelRunner(draft_model, self.mgr)
         self.d_runner.buffer = self.t_runner.buffer   # shared pool...
         self._shared_state()
-        self.tp = target_params if target_params is not None \
-            else target_model.init(seed)
-        self.dp = draft_params if draft_params is not None \
-            else draft_model.init(seed + 1)
+        self.tp = serving_params(target_params if target_params is not None
+                                 else target_model.init(seed))
+        self.dp = serving_params(draft_params if draft_params is not None
+                                 else draft_model.init(seed + 1))
         self.accept_lengths: List[int] = []
         # rounds whose draft chain was already in flight before the
         # previous round's accept decision reached the host

@@ -10,6 +10,7 @@ from repro.core.spec import lcm as _lcm
 from repro.models.lm import DecodeBatch
 from repro.models.registry import build_model
 from repro.models.tp import single_device_dist
+from repro.serving.engine import serving_params
 
 ARCH_IDS = sorted(ARCHS)
 
@@ -89,13 +90,8 @@ def make_serve_batch(model, cfg, B, T, n_tokens, *, prefill, buffer_units,
     return batch
 
 
-@pytest.mark.parametrize("arch", ARCH_IDS)
-def test_smoke_train_step(arch):
-    cfg = reduced(ARCHS[arch])
-    dist = single_device_dist()
-    model = build_model(cfg, dist)
-    params = model.init(0)
-    B, T = 2, 16
+def train_loss_fn(model, cfg, B=2, T=16):
+    """Jitted params -> train loss on a fixed (B, T) batch."""
     tokens = (jnp.arange(B * T, dtype=jnp.int32).reshape(B, T)
               % cfg.vocab_size)
     targets = jnp.roll(tokens, -1, axis=1)
@@ -108,10 +104,43 @@ def test_smoke_train_step(arch):
         kw["mm_mask"] = (jnp.arange(T)[None] < 2).repeat(B, 0)
         kw["mrope_pos"] = jnp.broadcast_to(
             jnp.arange(T, dtype=jnp.int32)[None, None], (3, B, T))
-    loss = jax.jit(lambda p: model.train_loss(p, tokens, targets, **kw))(params)
+    return jax.jit(lambda p: model.train_loss(p, tokens, targets, **kw))
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_smoke_train_step(arch):
+    cfg = reduced(ARCHS[arch])
+    dist = single_device_dist()
+    model = build_model(cfg, dist)
+    params = model.init(0)
+    loss = train_loss_fn(model, cfg)(params)
     assert loss.shape == ()
     assert np.isfinite(float(loss)), (arch, float(loss))
     assert 1.0 < float(loss) < 20.0, (arch, float(loss))
+
+
+@pytest.mark.parametrize("arch", ARCH_IDS)
+def test_serving_params_cast_only_matmul_weights(arch):
+    """``serving_params`` stores in bf16 only leaves the model rounds to
+    bf16 anyway: with every leaf moved off the bf16 grid (zero/one inits
+    included), the forward pass is bit-identical on the fp32 masters and
+    on the served tree, while casting every leaf does change it. The cast
+    leaves hold most of the bytes (the point of serving them in bf16)."""
+    cfg = reduced(ARCHS[arch])
+    model = build_model(cfg, single_device_dist())
+    leaves, treedef = jax.tree.flatten(model.init(0))
+    rng = np.random.default_rng(0)
+    params = jax.tree.unflatten(treedef, [
+        a + jnp.asarray(0.1 * rng.standard_normal(a.shape), a.dtype)
+        * (1 + jnp.abs(a)) for a in leaves])
+    loss = train_loss_fn(model, cfg)
+    served = serving_params(params)
+    assert float(loss(served)) == float(loss(params))
+    all_cast = jax.tree.map(lambda a: a.astype(jnp.bfloat16), params)
+    assert float(loss(all_cast)) != float(loss(params))
+    nbytes = {dt: sum(a.size for a in jax.tree.leaves(served)
+                      if a.dtype == dt) for dt in (jnp.bfloat16, jnp.float32)}
+    assert nbytes[jnp.bfloat16] > 0.8 * sum(nbytes.values()), nbytes
 
 
 @pytest.mark.parametrize("arch", ARCH_IDS)

@@ -180,8 +180,9 @@ def test_cross_attn_kernel_masked_rows_zero():
 
 def test_sparse_blocks_sizing():
     """Block sizing stays within kernel-friendly pow2 bounds and shrinks
-    with the problem so small packed steps still split into blocks."""
-    assert BA.sparse_blocks(16, 64) == (8, 64)
+    with the problem so small packed steps still split into blocks; the
+    minima are the TPU tile (8 query rows x 128 KV lanes)."""
+    assert BA.sparse_blocks(16, 64) == (8, 128)
     qb, kb = BA.sparse_blocks(128, 2048)
     assert qb == 32 and kb == 128
     qb, kb = BA.sparse_blocks(10_000, 100_000)
@@ -189,7 +190,7 @@ def test_sparse_blocks_sizing():
 
 
 # ----------------------------------------------------------- engine level
-@pytest.mark.parametrize("arch", ARCHS7)
+@pytest.mark.parametrize("arch", ARCHS7 + ["granite-3-2b+wide"])
 def test_kernel_matches_ref_greedy(arch):
     """attention_impl="kernel" reproduces the ref path's greedy outputs
     for every archetype, including mm/encoder item routing (vlm mrope +

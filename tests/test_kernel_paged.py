@@ -75,3 +75,44 @@ def test_page_id_permutation_invariance(seed, tpp, n_pages):
                                    interpret=True)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2k),
                                atol=3e-5, rtol=3e-5)
+
+
+@pytest.mark.parametrize("kvl,d", [(2, 16), (4, 64), (8, 64), (8, 120)])
+def test_row_write_gather_match_flat_view(kvl, d):
+    """``write_token_kv`` / ``gather_pages`` address the ``(rows, lanes)``
+    buffer by whole rows — several per token where kv_heads * head_dim
+    exceeds the lanes (2 and 4 rows here; 15 at the 64-lane width of
+    8 x 120). Both must agree with the plain flat view
+    ``(VP, L, 2, TPP, KVL, D)`` of the same units: dropped writes (eid < 0)
+    land nowhere, and invalid table entries gather zeros."""
+    from repro.core.layout import buffer_shape
+    from repro.core.spec import attention_spec
+    from repro.models import attention as A
+
+    vp, nl, tpp, layer = 6, 3, 4, 1
+    view_shape = (vp, nl, 2, tpp, kvl, d)
+    units = int(np.prod(view_shape))
+    spec = attention_spec("a", num_layers=nl, kv_heads=kvl, head_dim=d,
+                          tokens_per_page=tpp)
+    shape = buffer_shape([spec], units)
+    rng = np.random.default_rng(0)
+    flat = jnp.asarray(rng.standard_normal(units), jnp.float32)
+    buf = flat.reshape(shape[-2:])
+    eids = jnp.asarray([[0, 0, 3, -1], [5, 2, 2, 4]], jnp.int32)
+    slots = jnp.asarray([[1, 2, 0, 3], [3, 0, 1, 2]], jnp.int32)
+    k_new, v_new = (jnp.asarray(rng.standard_normal((2, 4, kvl, d)),
+                                jnp.float32) for _ in range(2))
+    out = A.write_token_kv(buf, view_shape, layer, eids, slots, k_new, v_new)
+    want = flat.reshape(view_shape)
+    ok = np.asarray(eids).reshape(-1) >= 0
+    e, s = (np.asarray(a).reshape(-1)[ok] for a in (eids, slots))
+    want = want.at[e, layer, 0, s].set(k_new.reshape(-1, kvl, d)[ok])
+    want = want.at[e, layer, 1, s].set(v_new.reshape(-1, kvl, d)[ok])
+    np.testing.assert_array_equal(out.reshape(view_shape), want)
+
+    tables = jnp.asarray([[2, 5, -1], [0, 3, 4]], jnp.int32)
+    k, v = A.gather_pages(out, view_shape, tables, layer)
+    pages = jnp.where((tables >= 0)[:, :, None, None, None, None],
+                      want[jnp.maximum(tables, 0), layer], 0)
+    np.testing.assert_array_equal(k, pages[:, :, 0].reshape(k.shape))
+    np.testing.assert_array_equal(v, pages[:, :, 1].reshape(v.shape))

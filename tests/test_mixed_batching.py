@@ -60,7 +60,8 @@ def test_serial_mode_schedules_one_prefill():
 # -------------------------------------------------------------- determinism
 @pytest.mark.parametrize("arch", ["granite-3-2b", "h2o-danube-3-4b",
                                   "qwen2-vl-2b", "zamba2-1.2b", "rwkv6-3b",
-                                  "whisper-tiny", "dbrx-132b"])
+                                  "whisper-tiny", "dbrx-132b",
+                                  "granite-3-2b+wide"])
 def test_packed_padded_serial_greedy_equal(arch):
     """Greedy outputs are token-identical across all three batching
     layouts — packed stream, padded rows, and the legacy

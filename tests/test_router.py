@@ -304,8 +304,8 @@ def test_autotuner_shard_window_scaling():
     fleet size), but the observation window scales with N — a shard sees
     1/N of the traffic, so it needs N x the steps before moving budgets."""
     _, cfg, _ = get_model("granite-3-2b")
-    one = BudgetAutotuner(cfg)
-    four = BudgetAutotuner(cfg, num_shards=4)
+    one = BudgetAutotuner(cfg, "TPU v5 lite")
+    four = BudgetAutotuner(cfg, "TPU v5 lite", num_shards=4)
     assert four.budget == one.budget
     assert four.prefill_cap == one.prefill_cap
     assert four.window == 4 * one.window
@@ -313,7 +313,11 @@ def test_autotuner_shard_window_scaling():
     assert shard_pool_bytes(3, 8) == 1      # floor, never zero
 
 
-def test_fleet_autotuned_budgets_per_shard():
+def test_fleet_autotuned_budgets_per_shard(monkeypatch):
+    # the budget seed models a v5e; the fleet itself runs on the CPU
+    from repro.serving import dp_engine, engine
+    for mod in (engine, dp_engine):
+        monkeypatch.setattr(mod, "device_kind", lambda model: "TPU v5 lite")
     dp = _dp(n=2, autotune_budgets=True)
     for sh in dp.shards:
         assert sh.engine.autotuner is not None

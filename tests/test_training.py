@@ -8,7 +8,7 @@ import pytest
 
 from repro.configs import ARCHS, reduced
 from repro.models.registry import build_model
-from repro.models.tp import shard_map, single_device_dist
+from repro.models.tp import single_device_dist
 from repro.training import (AdamWConfig, SyntheticLM, Trainer, TrainerConfig,
                             compressed_psum)
 
@@ -69,7 +69,7 @@ def test_compressed_psum_error_feedback():
         total, err = compressed_psum(x, "d")
         return total, err
 
-    total, err = jax.jit(shard_map(
+    total, err = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(jax.sharding.PartitionSpec("d"),),
         out_specs=(jax.sharding.PartitionSpec("d"),) * 2))(x)
     # quantization error is carried, not lost

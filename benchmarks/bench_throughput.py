@@ -202,9 +202,6 @@ def run_pipeline_ab(arch: str = "granite-3-2b", n_req=16, prompt=96, out=24,
             host_sample_ms_total=sum(m.host_sample_ms for m in ms),
             host_build_ms_total=sum(m.host_build_ms for m in ms),
             dispatch_wait_ms_total=sum(m.dispatch_ms for m in ms),
-            dispatch_issue_ms_total=sum(m.dispatch_issue_ms for m in ms),
-            dispatch_queue_ms_total=sum(m.dispatch_queue_ms for m in ms),
-            dispatch_compute_ms_total=sum(m.dispatch_compute_ms for m in ms),
             spec_kills=eng.spec_kills,
         )
     base = rows["depth2_host"]
@@ -453,9 +450,6 @@ def run_disagg_ab(arch: str = "granite-3-2b", n_req=12, prompt=96, out=24,
                 steps=sh.engine.step_count,
                 prefill_tokens=pf,
                 decode_tokens=tot - pf,
-                dispatch_issue_ms=sum(m.dispatch_issue_ms for m in ms),
-                dispatch_queue_ms=sum(m.dispatch_queue_ms for m in ms),
-                dispatch_compute_ms=sum(m.dispatch_compute_ms for m in ms),
                 host_build_ms=sum(m.host_build_ms for m in ms),
             ))
         lat = sum(dp.finish_tick[r] - dp.submit_tick[r]

@@ -94,16 +94,11 @@ class BudgetAutotuner:
             return False
         n = len(self._hist)
         # host side includes sampling (0 under device sampling); the device
-        # side prefers the pipeline timing split's compute estimate when
-        # the engine runs deep enough to report it (depth > 1), falling
-        # back to the blocked-fetch wait (sync loop / metrics without the
-        # split). Comparing host-vs-fetch alone would under-read device
-        # time exactly when pipelining hides it best.
+        # side is the time the step spent in dispatch and blocked on the
+        # device (async: the blocked fetch of the completed step)
         host = sum(x.host_build_ms + getattr(x, "host_sample_ms", 0.0)
                    for x in self._hist) / n
-        disp = sum(x.dispatch_compute_ms
-                   if getattr(x, "dispatch_compute_ms", 0.0) > 0
-                   else x.dispatch_ms for x in self._hist) / n
+        disp = sum(x.dispatch_ms for x in self._hist) / n
         half = n // 2
         byts_early = sum(x.attn_bytes_modeled
                          for x in list(self._hist)[:half])

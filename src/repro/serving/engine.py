@@ -55,8 +55,11 @@ determinism tests.
 Collects the per-step metrics the paper's figures are built from (decode
 batch size Fig.15, memory breakdown Fig.16, hit rates Fig.17, encoder runs
 Fig.18) plus the mixed-batch packing stats (tokens/step, prefills/step),
-dispatch-waste counters (tokens vs slots paid), and the host-build /
-device-wait timings the async overlap is measured by."""
+dispatch-waste counters (tokens vs slots paid), the host-build /
+device-wait timings the async overlap is measured by, the tokens computed
+again after recompute preemption, and the milliseconds of each named phase
+of the step (``serving.spans``; the same spans land in a running profiler's
+trace)."""
 from __future__ import annotations
 
 import dataclasses
@@ -75,6 +78,7 @@ from .request import Request, SamplingParams, Status
 from .runner import ModelRunner
 from .sampler import TIE_EPS, greedy_token, host_sample, rid_hash
 from .scheduler import ScheduledSeq, Scheduler, SchedulerConfig, StepPlan
+from .spans import phase
 
 
 # Greedy-sampling tie band (re-exported from serving.sampler, the single
@@ -223,14 +227,6 @@ class StepMetrics:
     # host build already ran (the overlap win is host_build_ms no longer
     # serializing with it).
     dispatch_ms: float = 0.0
-    # Pipeline timing split (async; host-observed estimates). issue: time
-    # spent in runner.dispatch() handing work to the device. For each step
-    # COMPLETED during this call: queue = time it sat behind the previous
-    # step's completion, compute = completion minus max(issue, previous
-    # completion). dispatch_ms above stays the blocked-fetch wait.
-    dispatch_issue_ms: float = 0.0
-    dispatch_queue_ms: float = 0.0
-    dispatch_compute_ms: float = 0.0
     # Host-side sampling time (greedy argmax / seeded draw in _sample);
     # 0 under device sampling — that is the point.
     host_sample_ms: float = 0.0
@@ -247,6 +243,14 @@ class StepMetrics:
     kv_blocks_skipped: int = 0
     attn_flops_modeled: float = 0.0
     attn_bytes_modeled: float = 0.0
+    # Tokens dispatched at positions their request had computed before a
+    # recompute preemption (prefix-cache hits at re-admission excluded):
+    # the device work the preempt-and-readmit cycle throws away.
+    recomputed_tokens: int = 0
+    # Milliseconds of each named phase of this step (serving.spans): one
+    # key per phase that ran, summed over its spans; complete once
+    # ``Engine.step`` has returned.
+    phase_ms: Dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -278,7 +282,6 @@ class _InflightStep:
     epochs: List[int]          # per-segment seq.epoch at dispatch time
     live: List[bool]           # False: segment killed at reconciliation
     step: int                  # engine step index this dispatch was logged as
-    dispatched_at: float = 0.0  # perf_counter at issue (timing split)
 
 
 class Engine:
@@ -366,7 +369,6 @@ class Engine:
         self._attn_seen = (0, 0, 0.0, 0.0)
         self._bytes_seen = 0
         self._sample_ms = 0.0           # host sampling time this step
-        self._last_complete_t = 0.0     # timing split (queue vs compute)
 
     # ------------------------------------------------- baseline semantics
     def _apply_baseline_semantics(self):
@@ -401,16 +403,22 @@ class Engine:
 
     # ---------------------------------------------------------------- step
     def step(self) -> Optional[StepMetrics]:
-        if self.async_scheduling:
-            return self._step_async()
-        if not self.scheduler.has_work():
+        if not self.scheduler.has_work() and not self._inflight:
             return None
-        t0 = time.perf_counter()
-        plan = self.scheduler.schedule()
+        ph: Dict[str, float] = {}
+        with phase("engine.step", ph):
+            if self.async_scheduling:
+                return self._step_async(ph)
+            return self._step_sync(ph)
+
+    def _step_sync(self, ph: Dict[str, float]) -> StepMetrics:
+        with phase("engine.schedule", ph) as sched:
+            plan = self.scheduler.schedule()
         # state restores of this step's admissions: one batched dispatch
-        self.runner.apply_copies(plan.copy_ops)
+        with phase("engine.copies", ph) as restore:
+            self.runner.apply_copies(plan.copy_ops)
         # scheduling counts as host build time (async hides it too)
-        build_ms = (time.perf_counter() - t0) * 1e3
+        build_ms = sched.ms + restore.ms
         disp_ms = 0.0
 
         slots_before = self.runner.slots_dispatched
@@ -426,27 +434,33 @@ class Engine:
             packed = self.cfg.batching_mode == "packed"
             post_ops: List[StateCopyOp] = []
             for group in groups:
-                tb = time.perf_counter()
-                prep = self.runner.prepare(
-                    [(s.req, s.num_tokens, s.start) for s in group],
-                    packed=packed)
-                td = time.perf_counter()
-                build_ms += (td - tb) * 1e3
+                with phase("runner.prepare", ph) as prep_span:
+                    prep = self.runner.prepare(
+                        [(s.req, s.num_tokens, s.start) for s in group],
+                        packed=packed)
+                build_ms += prep_span.ms
                 for s in group:     # device work now exists for these
                     s.req.started = True
-                logits = self.runner.fetch(
-                    self.runner.dispatch(self.params, prep), len(group))
-                disp_ms += (time.perf_counter() - td) * 1e3
+                with phase("runner.dispatch", ph) as issue:
+                    handle = self.runner.dispatch(self.params, prep)
+                with phase("runner.fetch", ph) as fetch:
+                    logits = self.runner.fetch(handle, len(group))
+                disp_ms += issue.ms + fetch.ms
                 # sampling/advance below is neither build nor dispatch wait
-                for i, s in enumerate(group):
-                    post_ops.extend(self._advance(s, logits[i]))
+                with phase("engine.advance", ph):
+                    for i, s in enumerate(group):
+                        post_ops.extend(self._advance(s, logits[i]))
             # checkpoint copies emitted while advancing: one batched dispatch
-            self.runner.apply_copies(post_ops)
+            with phase("engine.copies", ph):
+                self.runner.apply_copies(post_ops)
 
-        return self._record_metrics(plan, slots_before, build_ms, disp_ms)
+        with phase("engine.record", ph):
+            return self._record_metrics(
+                plan, slots_before, build_ms, disp_ms, phase_ms=ph,
+                recomputed=plan.recomputed_tokens)
 
     # ---------------------------------------------------------- async step
-    def _step_async(self) -> Optional[StepMetrics]:
+    def _step_async(self, ph: Dict[str, float]) -> StepMetrics:
         """One pipelined step: plan + host-build the next step (the part
         the in-flight dispatches hide), THEN complete the oldest in-flight
         step(s) until a ring slot is free, reconcile the new plan AND every
@@ -454,9 +468,6 @@ class Engine:
         requests that finished, roll back their speculative pages, patch
         or board-feed the decode token ids), and dispatch the new step
         without waiting for it."""
-        if not self.scheduler.has_work() and not self._inflight:
-            return None
-
         # --- phase 1: plan the next step while the ring executes on device.
         # Effective positions count every VALID queued row (stale-epoch
         # rows — preempted or restarted while queued — are dead weight the
@@ -478,16 +489,19 @@ class Engine:
         san = self.mgr.sanitizer
         if san is not None:
             san.set_inflight(inflight_info)
-        plan = self.scheduler.schedule(inflight=inflight_info)
-        self.runner.apply_copies(plan.copy_ops)
+        with phase("engine.schedule", ph):
+            plan = self.scheduler.schedule(inflight=inflight_info)
+        with phase("engine.copies", ph):
+            self.runner.apply_copies(plan.copy_ops)
         prepared = None
         if plan.scheduled:
             self._count_encoder_runs(plan.scheduled)
-            prepared = self.runner.prepare(
-                [(s.req, s.num_tokens, s.start) for s in plan.scheduled],
-                packed=self.cfg.batching_mode == "packed",
-                sample=self.device_sampling,
-                board_feed=self.device_sampling)
+            with phase("runner.prepare", ph):
+                prepared = self.runner.prepare(
+                    [(s.req, s.num_tokens, s.start) for s in plan.scheduled],
+                    packed=self.cfg.batching_mode == "packed",
+                    sample=self.device_sampling,
+                    board_feed=self.device_sampling)
         build_ms = (time.perf_counter() - t0) * 1e3
 
         # --- phase 2: complete the oldest step(s). Completing down to
@@ -499,7 +513,7 @@ class Engine:
         # schedule. This also keeps step counts depth-independent: deeper
         # rings don't pay extra one-completion-per-call shutdown steps.
         done: List[Request] = []
-        wait_ms = queue_ms = compute_ms = 0.0
+        wait_ms = 0.0
         target = self._ring_capacity - 1 if prepared is not None else 0
         while len(self._inflight) > target:
             inf = self._inflight.popleft()
@@ -511,11 +525,9 @@ class Engine:
             deeper = self._live_inflight_rids()
             if san is not None:
                 san.set_inflight(deeper)
-            d, w, q, c = self._complete(inf, deeper)
+            d, w = self._complete(inf, ph, deeper)
             done.extend(d)
             wait_ms += w
-            queue_ms += q
-            compute_ms += c
 
         # --- phase 3: reconcile the new plan AND every queued plan
         # against the completed steps' actual outcomes
@@ -563,23 +575,24 @@ class Engine:
         # call, once it reaches the head of the ring)
         slots_before = self.runner.slots_dispatched
         tokens_before = self.runner.tokens_dispatched
-        issue_ms = 0.0
+        recomputed = 0
         if prepared is not None and any(live):
             epochs = [s.req.seq.epoch for s in plan.scheduled]
             for s in plan.scheduled:    # device work now exists for these
                 s.req.started = True
-            ti = time.perf_counter()
-            handle = self.runner.dispatch(self.params, prepared)
-            issue_ms = (time.perf_counter() - ti) * 1e3
+            with phase("runner.dispatch", ph):
+                handle = self.runner.dispatch(self.params, prepared)
             self._inflight.append(_InflightStep(
-                plan, handle, epochs, live, step=self.step_count,
-                dispatched_at=ti))
+                plan, handle, epochs, live, step=self.step_count))
+            recomputed = sum(s.recomputed for s, alive
+                             in zip(plan.scheduled, live) if alive)
         if san is not None:
             san.set_inflight(self._live_inflight_rids())
-        return self._record_metrics(
-            plan, slots_before, build_ms, wait_ms,
-            tokens=self.runner.tokens_dispatched - tokens_before,
-            issue_ms=issue_ms, queue_ms=queue_ms, compute_ms=compute_ms)
+        with phase("engine.record", ph):
+            return self._record_metrics(
+                plan, slots_before, build_ms, wait_ms,
+                tokens=self.runner.tokens_dispatched - tokens_before,
+                phase_ms=ph, recomputed=recomputed)
 
     def _live_inflight_rids(self) -> Set[str]:
         """Rids with live, epoch-valid segments still queued in the ring —
@@ -592,7 +605,7 @@ class Engine:
                     rids.add(s.req.rid)
         return rids
 
-    def _complete(self, inf: _InflightStep,
+    def _complete(self, inf: _InflightStep, ph: Dict[str, float],
                   deeper_rids: frozenset = frozenset()):
         """Fetch an in-flight step's results and run its delayed
         sample/advance. Device sampling blocks on the (segments,) int32
@@ -601,52 +614,44 @@ class Engine:
         Segments whose request was preempted while in flight (stale epoch)
         or killed at reconciliation are skipped — recompute preemption
         regenerates their tokens deterministically. Returns (finished
-        requests, fetch-block ms, queue ms, compute ms) — finish itself is
-        deferred to the caller so it can reconcile the queued plans first,
-        and only the device wait is timed (host bookkeeping after the
-        fetch is not dispatch latency)."""
-        t0 = time.perf_counter()
+        requests, fetch-block ms) — finish itself is deferred to the caller
+        so it can reconcile the queued plans first, and only the device
+        wait is timed (host bookkeeping after the fetch is not dispatch
+        latency)."""
         n = len(inf.plan.scheduled)
         tokens = logits = None
-        if self.device_sampling:
-            tokens = self.runner.fetch_tokens(inf.handle, n)
-            if self.cfg.record_sample_logits:
+        with phase("runner.fetch", ph) as fetch:
+            if self.device_sampling:
+                tokens = self.runner.fetch_tokens(inf.handle, n)
+                if self.cfg.record_sample_logits:
+                    logits = self.runner.fetch(inf.handle, n)
+            else:
                 logits = self.runner.fetch(inf.handle, n)
-        else:
-            logits = self.runner.fetch(inf.handle, n)
-        now = time.perf_counter()
-        wait_ms = (now - t0) * 1e3
-        # host-observed pipeline split: time queued behind the previous
-        # completion vs time actually computing (estimates — the device
-        # executes dispatches in order, so the previous completion bounds
-        # this step's start from below)
-        prev = self._last_complete_t or inf.dispatched_at
-        queue_ms = max(0.0, (prev - inf.dispatched_at) * 1e3)
-        compute_ms = max(0.0, (now - max(inf.dispatched_at, prev)) * 1e3)
-        self._last_complete_t = now
         done: List[Request] = []
         post_ops: List[StateCopyOp] = []
-        for i, s in enumerate(inf.plan.scheduled):
-            req, seq = s.req, s.req.seq
-            if not inf.live[i] or req.status != Status.RUNNING \
-                    or seq.epoch != inf.epochs[i] \
-                    or seq.num_computed != s.start:
-                continue
-            # stamp with the COMPLETED step's index, not the current call's
-            # (sync records the sampling step; async samples k calls later)
-            post_ops.extend(self._advance(
-                s, None if logits is None else logits[i],
-                done=done, step=inf.step,
-                token=None if tokens is None else int(tokens[i]),
-                allow_checkpoints=req.rid not in deeper_rids))
-        self.runner.apply_copies(post_ops)
-        return done, wait_ms, queue_ms, compute_ms
+        with phase("engine.advance", ph):
+            for i, s in enumerate(inf.plan.scheduled):
+                req, seq = s.req, s.req.seq
+                if not inf.live[i] or req.status != Status.RUNNING \
+                        or seq.epoch != inf.epochs[i] \
+                        or seq.num_computed != s.start:
+                    continue
+                # stamp with the COMPLETED step's index, not the current
+                # call's (sync records the sampling step; async samples k
+                # calls later)
+                post_ops.extend(self._advance(
+                    s, None if logits is None else logits[i],
+                    done=done, step=inf.step,
+                    token=None if tokens is None else int(tokens[i]),
+                    allow_checkpoints=req.rid not in deeper_rids))
+        with phase("engine.copies", ph):
+            self.runner.apply_copies(post_ops)
+        return done, fetch.ms
 
     def _record_metrics(self, plan: StepPlan, slots_before: int,
                         build_ms: float, disp_ms: float,
-                        tokens: Optional[int] = None,
-                        issue_ms: float = 0.0, queue_ms: float = 0.0,
-                        compute_ms: float = 0.0) -> StepMetrics:
+                        phase_ms: Dict[str, float], recomputed: int,
+                        tokens: Optional[int] = None) -> StepMetrics:
         """``batched_tokens``/``dispatched_slots``/``pad_slots`` describe
         what was actually DISPATCHED (async: killed speculative segments'
         tokens drop out and their slots count as padding waste; a fully
@@ -674,15 +679,14 @@ class Engine:
             pad_slots=max(0, slots - tokens),
             host_build_ms=build_ms,
             dispatch_ms=disp_ms,
-            dispatch_issue_ms=issue_ms,
-            dispatch_queue_ms=queue_ms,
-            dispatch_compute_ms=compute_ms,
             host_sample_ms=self._sample_ms,
             sampled_bytes_fetched=r.bytes_fetched - self._bytes_seen,
             kv_blocks_scanned=attn_delta[0],
             kv_blocks_skipped=attn_delta[1],
             attn_flops_modeled=attn_delta[2],
             attn_bytes_modeled=attn_delta[3],
+            recomputed_tokens=recomputed,
+            phase_ms=phase_ms,
         )
         self.metrics.append(m)
         self._sample_ms = 0.0

@@ -36,6 +36,9 @@ class Request:
     seq: Optional[SequenceState] = None
     output: List[int] = dataclasses.field(default_factory=list)
     preemptions: int = 0
+    # positions below this were computed before a recompute preemption
+    # threw them away: the most ever computed (or in flight) at a preemption
+    computed_hwm: int = 0
     first_token_step: Optional[int] = None
     finished_step: Optional[int] = None
     # ---- routing metadata (multi-engine data-parallel serving) ----
@@ -114,6 +117,7 @@ class Request:
         self.seq = None
         self.output = []
         self.started = False
+        self.computed_hwm = 0
         self.first_token_step = None
         self.finished_step = None
 

@@ -90,11 +90,18 @@ class ScheduledSeq:
     ``is_prefill`` is snapshotted at schedule time (advancing the sequence
     flips ``req.in_prefill`` before step metrics are read). ``start``
     equals ``seq.num_computed`` for synchronous plans and runs ahead of it
-    by the in-flight token count under async scheduling."""
+    by the in-flight token count under async scheduling. ``recomputed``
+    counts the tokens at positions the request had computed before a
+    recompute preemption (below ``req.computed_hwm``), snapshotted here."""
     req: Request
     num_tokens: int
     is_prefill: bool = False
     start: int = -1
+    recomputed: int = dataclasses.field(init=False, default=0)
+
+    def __post_init__(self):
+        self.recomputed = max(0, min(self.start + self.num_tokens,
+                                     self.req.computed_hwm) - self.start)
 
 
 @dataclasses.dataclass
@@ -111,11 +118,13 @@ class StepPlan:
     copy_ops: List[StepCopy] = dataclasses.field(default_factory=list)
     total_tokens: int = dataclasses.field(init=False, default=0)
     prefill_tokens: int = dataclasses.field(init=False, default=0)
+    recomputed_tokens: int = dataclasses.field(init=False, default=0)
 
     def __post_init__(self):
         self.total_tokens = sum(s.num_tokens for s in self.scheduled)
         self.prefill_tokens = sum(s.num_tokens for s in self.scheduled
                                   if s.is_prefill)
+        self.recomputed_tokens = sum(s.recomputed for s in self.scheduled)
 
     @property
     def decodes(self) -> List[Request]:
@@ -143,7 +152,8 @@ class Scheduler:
         # repeatedly deferring/preempting is out of memory headroom — more
         # traffic makes it worse, not faster).
         self.defer_count = 0
-        self._inflight_rids: frozenset = frozenset()
+        # the last schedule()'s in-flight map: rid -> (tokens, samples)
+        self._inflight: Dict[str, Tuple[int, int]] = {}
 
     def add(self, req: Request) -> None:
         self.waiting.append(req)
@@ -183,7 +193,7 @@ class Scheduler:
         # normalize values to (tokens_in_flight, samples_in_flight)
         inflight = {rid: v if isinstance(v, tuple) else (v, 1)
                     for rid, v in (inflight or {}).items()}
-        self._inflight_rids = frozenset(inflight)
+        self._inflight = inflight
 
         # 1) admit new requests while capacity allows; begin_request acquires
         #    prefix-cache hits and may emit state-restore copy ops.
@@ -334,10 +344,15 @@ class Scheduler:
                                           order.get(key(it).rid, -1)))
 
     def _preempt(self, req: Request) -> None:
+        # positions computed so far, and those the device is computing,
+        # are computed again after re-admission (less any prefix hit)
+        in_flight = self._inflight.get(req.rid, (0, 0))[0]
+        req.computed_hwm = max(req.computed_hwm,
+                               req.seq.num_computed + in_flight)
         # an in-flight victim's device state runs ahead of its hash chains —
         # releasing its pages to the prefix cache would poison later hits
         self.mgr.preempt_request(req.seq,
-                                 cache=req.rid not in self._inflight_rids)
+                                 cache=req.rid not in self._inflight)
         req.preemptions += 1
         self.preemption_count += 1
         req.status = Status.WAITING

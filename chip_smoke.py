@@ -1,11 +1,11 @@
 """Chip smoke run: serve granite-3-2b at its published widths on one TPU.
 
 Builds the model with random weights from ``--seed``, checks the compiled
-varlen kernel against its jnp oracle, and serves a small seeded request mix
-through ``Engine`` three times in one process: the default path (jnp
-reference attention, synchronous loop, 512-token steps), the same path with
-256-token steps, and ``attention_impl="kernel"`` (the Pallas varlen
-kernel). It checks that every request finished with finite logits, that
+in-place paged attention kernel against its jnp oracle, and serves a small
+seeded request mix through ``Engine`` three times in one process: the
+gather path (jnp reference attention, synchronous loop, 512-token steps),
+the same path with 256-token steps, and ``attention_impl="kernel"`` (the
+Pallas paged kernel reading the KV pool in place). It checks that every request finished with finite logits, that
 both other legs agree with the default one on greedy tokens (fork-aware,
 see ``repro.analysis.greedy_equiv``, with the fixed limits below), that
 the kernel leg's compiled serve step holds a ``tpu_custom_call`` (no
@@ -38,9 +38,9 @@ PROMPTS = (("r0", 64, False), ("r1", 200, False), ("r2", 448, False),
            ("r3", 608, True), ("r4", 1000, False), ("r5", 1536, False),
            ("r6", 2048, False))
 LATE = ("r7", 672, True)      # arrives once r3's prefix is cached
-# (leg, attention_impl, token budget per step): the default path, the same
+# (leg, attention_impl, token budget per step): the gather path, the same
 # reference attention under another schedule (measures its own numerical
-# noise at this width), and the Pallas kernel
+# noise at this width), and the in-place Pallas kernel
 LEGS = (("ref", "ref", 512), ("ref-256", "ref", 256),
         ("kernel", "kernel", 512))
 # Agreement limits between two legs, fixed (PERF.md, Findings). At 40
@@ -155,36 +155,45 @@ def print_agreement(ref, other):
 
 
 def kernel_vs_oracle(t, s, interpret):
-    """Largest |error| of the varlen kernel against its f32 jnp oracle,
-    relative to the oracle's largest |output|, on one seeded packed stream
-    at granite's 32 heads x head_dim 64 and the serve path's bf16: four
-    segments whose query chunks start at the segment's first position (a
-    fresh prompt), at its end, and between."""
+    """Largest |error| of the in-place paged kernel against its f32 jnp
+    oracle, relative to the oracle's largest |output|, on one seeded packed
+    step at granite's 8 KV heads x 4 query groups x head_dim 64 and the
+    serve path's bf16: four segments of t/4 query tokens over s/4 slots of
+    shuffled pool pages, whose chunks start at the segment's first
+    position (a fresh prompt), at its end, and between."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
-    from repro.kernels.flash_attention.kernel import (
-        flash_attention_varlen_tpu)
-    from repro.kernels.flash_attention.ref import flash_attention_varlen_ref
-    from repro.models.blocks_attn import sparse_blocks
+    from repro.kernels.paged_attention.kernel import (paged_attention,
+                                                      plan_work)
+    from repro.kernels.paged_attention.ref import paged_attention_ref
+    from repro.models.blocks_attn import paged_pages_per_wave, paged_q_block
 
+    kvl, g, d, tpp, nl = 8, 4, 64, 16, 2
     rng = np.random.default_rng(0)
-    q, k, v = (jnp.asarray(rng.standard_normal((32, n, 64)), jnp.bfloat16)
-               for n in (t, s, s))
     seg_t, seg_s = t // 4, s // 4
+    npg = seg_s // tpp
+    vp = 4 * npg + 3
+    rows = jnp.asarray(rng.standard_normal(
+        (vp * nl, 2 * tpp * kvl * d // 128, 128)), jnp.bfloat16)
+    q = jnp.asarray(rng.standard_normal((t, kvl, g, d)), jnp.bfloat16)
     start = np.arange(4) * (seg_s - seg_t) // 3
-    q_seg = np.repeat(np.arange(4), seg_t).astype(np.int32)
-    q_pos = (start[:, None] + np.arange(seg_t)).reshape(-1).astype(np.int32)
-    kv_seg = np.repeat(np.arange(4), seg_s).astype(np.int32)
-    kv_pos = np.tile(np.arange(seg_s), 4).astype(np.int32)
-    meta = tuple(map(jnp.asarray, (q_seg, kv_seg, q_pos, kv_pos)))
-    blk_q, blk_k = sparse_blocks(t, s)
-    out = flash_attention_varlen_tpu(q, k, v, *meta, blk_q=blk_q,
-                                     blk_k=blk_k, interpret=interpret)
+    seg = np.repeat(np.arange(4), seg_t).astype(np.int32)
+    pos = (start[:, None] + np.arange(seg_t)).reshape(-1).astype(np.int32)
+    ids = jnp.asarray(rng.permutation(vp)[:4 * npg].astype(np.int32))
+    runs = (np.arange(4, dtype=np.int32) * npg, np.full(4, npg, np.int32))
+    meta = tuple(map(jnp.asarray, (seg, pos) + runs))
+    qb = paged_q_block(kvl, g)
+    work = plan_work(*meta, q_block=qb, tokens_per_page=tpp)
+    out = paged_attention(
+        q, rows, ids, work, 1, num_layers=nl, tokens_per_page=tpp,
+        q_block=qb, interpret=interpret,
+        pages_per_wave=paged_pages_per_wave((vp, nl, 2, tpp, kvl, d)))
     with jax.default_matmul_precision("highest"):
-        want = flash_attention_varlen_ref(
-            *(a.astype(jnp.float32) for a in (q, k, v)), *meta)
+        want = paged_attention_ref(
+            q.astype(jnp.float32), rows.astype(jnp.float32), ids, *meta, 1,
+            num_layers=nl, tokens_per_page=tpp)
     err = jnp.abs(out.astype(jnp.float32) - want).max()
     return float(err / jnp.abs(want).max())
 
@@ -272,10 +281,10 @@ def main(argv=None) -> int:
           f"{MAX_NEW_TOKENS} new tokens each; pool {pool / 2**30:.2f} GiB")
 
     err = kernel_vs_oracle(256, 2048, interpret=args.cpu_rehearsal)
-    print(f"varlen kernel vs f32 jnp oracle (bf16, 4 segments, t=256, "
+    print(f"paged kernel vs f32 jnp oracle (bf16, 4 segments, t=256, "
           f"s=2048): max |error| / max |output| {err:.4e} "
           f"(limit {ORACLE_TOL:.4e})")
-    check(err <= ORACLE_TOL, "varlen kernel disagrees with its oracle")
+    check(err <= ORACLE_TOL, "paged kernel disagrees with its oracle")
 
     engines = {}
     params = None

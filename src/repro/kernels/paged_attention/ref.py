@@ -1,4 +1,4 @@
-"""Pure-jnp oracle for the paged decode attention kernel."""
+"""Pure-jnp oracle for the in-place paged attention kernel."""
 from __future__ import annotations
 
 import jax.numpy as jnp
@@ -6,25 +6,39 @@ import jax.numpy as jnp
 NEG_INF = -1e30
 
 
-def paged_decode_attention_ref(q, kv_view, tables, page_pos, positions, *,
-                               window: int = 0):
-    """Same contract as kernel.paged_decode_attention."""
-    b, kvl, g, d = q.shape
-    vp, _, tpp, _, _ = kv_view.shape
-    pages = jnp.take(kv_view, jnp.maximum(tables, 0), axis=0)
-    # (B, P, 2, TPP, KVL, D)
-    k = pages[:, :, 0].reshape(b, -1, kvl, d).astype(jnp.float32)
-    v = pages[:, :, 1].reshape(b, -1, kvl, d).astype(jnp.float32)
-    slot_pos = (page_pos[:, :, None]
-                + jnp.arange(tpp)[None, None, :]).reshape(b, -1)
-    mask = slot_pos <= positions[:, None]
+def paged_attention_ref(q, rows, page_ids, seg_ids, positions, page_start,
+                        page_count, layer, *, num_layers, tokens_per_page,
+                        window=0):
+    """Same contract as ``kernel.paged_attention``, from the segments'
+    page runs instead of the kernel's work plan: token i attends slot j
+    iff j lies in a page of i's own segment run, the page id is valid,
+    and j's position is at most i's (and inside the window)."""
+    t, kvl, g, d = q.shape
+    tpp = tokens_per_page
+    n = page_ids.shape[0]
+    pages = rows[jnp.maximum(page_ids, 0) * num_layers + layer]
+    half = pages.shape[1] // 2
+    k = pages[:, :half].reshape(n * tpp, kvl, d)
+    v = pages[:, half:].reshape(n * tpp, kvl, d)
+    idx = jnp.arange(n)
+    in_run = ((idx[None] >= page_start[:, None])
+              & (idx[None] < (page_start + page_count)[:, None]))   # (S, P)
+    page_seg = jnp.where(in_run.any(0) & (page_ids >= 0),
+                         jnp.argmax(in_run, axis=0), -2)
+    page_rel = idx - page_start[jnp.maximum(page_seg, 0)]
+    slot_seg = jnp.repeat(page_seg, tpp)
+    slot_pos = (page_rel[:, None] * tpp + jnp.arange(tpp)).reshape(-1)
+    mask = ((seg_ids[:, None] == slot_seg[None])
+            & (slot_pos[None] <= positions[:, None]))
     if window:
-        mask &= slot_pos > (positions[:, None] - window)
-    scale = 1.0 / (d ** 0.5)
-    logit = jnp.einsum("bkgd,bskd->bkgs", q.astype(jnp.float32) * scale, k)
-    logit = jnp.where(mask[:, None, None, :], logit, NEG_INF)
+        mask &= slot_pos[None] > positions[:, None] - window
+    logit = jnp.einsum("tkgd,skd->kgts", q, k,
+                       preferred_element_type=jnp.float32)
+    logit = jnp.where(mask, logit, NEG_INF)
     m = jnp.max(logit, axis=-1, keepdims=True)
-    p = jnp.exp(logit - m)
+    p = jnp.where(mask, jnp.exp(logit - m), 0.0)
     l = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bkgs,bskd->bkgd", p / jnp.maximum(l, 1e-30), v)
-    return out.astype(q.dtype)
+    out = jnp.einsum("kgts,skd->kgtd", p.astype(v.dtype), v,
+                     preferred_element_type=jnp.float32)
+    out = out / jnp.maximum(l, 1e-30)
+    return jnp.moveaxis(out, 2, 0).astype(q.dtype)

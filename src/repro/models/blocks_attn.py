@@ -13,6 +13,7 @@ import jax.numpy as jnp
 
 from ..kernels.flash_attention.kernel import (block_intervals,
                                               flash_attention_varlen_tpu)
+from ..kernels.paged_attention import kernel as PK
 from . import attention as A
 from .common import dense, rms_norm
 from .rotary import apply_mrope, apply_rope
@@ -124,7 +125,7 @@ def attn_compute(p, x, gathered, dist: Dist, *, kv_local, head_dim,
                  positions, seq_lens, window=0, rope_theta=1e6,
                  mrope_positions=None, norm_eps=1e-5, prefill=False,
                  sp_axis: Optional[str] = None, kv_groups=None,
-                 seg_ids=None, chunk_start=None, impl="ref"):
+                 seg_ids=None, chunk_start=None):
     """Phase 2 (COMPUTE): attention over gathered old pages + this step's
     fresh K/V (still in registers — the buffer write happens in phase 3).
 
@@ -138,13 +139,7 @@ def attn_compute(p, x, gathered, dist: Dist, *, kv_local, head_dim,
     ``chunk_start`` (B, T) each token's chunk-start position (several
     sequences share one stream row); both masks then additionally require
     segment equality, using the slot_seg returned by ``attn_gather``.
-
-    impl="kernel" dispatches the packed layout through the Pallas varlen
-    flash kernel (one block-sparse call over old++fresh KV, interpret
-    mode off-TPU) instead of the jnp reference. Falls back to ref for
-    non-packed layouts and for kv_groups/sp_axis sharding (the kernel
-    returns normalized output, so cross-shard partial combining doesn't
-    apply). Returns (x_out, k_fresh, v_fresh)."""
+    Returns (x_out, k_fresh, v_fresh)."""
     k_all, v_all, slot_pos, slot_seg = gathered
     b, t, _ = x.shape
     xn = rms_norm(x, p["attn_norm"], norm_eps)
@@ -154,14 +149,6 @@ def attn_compute(p, x, gathered, dist: Dist, *, kv_local, head_dim,
     packed = seg_ids is not None
     if chunk_start is None:
         chunk_start = positions[:, :1]                         # (B, 1)
-    if (packed and impl == "kernel" and kv_groups is None
-            and sp_axis is None):
-        out = packed_kernel_attention(
-            q, k_all, v_all, slot_pos, slot_seg, k, v, positions, seg_ids,
-            chunk_start, window=window)
-        out = out.reshape(b, t, -1).astype(x.dtype)
-        y = dense(out, p["o"])
-        return x + psum_tp(y, dist), k, v
     if prefill or packed:
         o, m, l = _prefill_flash(q, k_all, v_all, slot_pos, positions,
                                  chunk_start=chunk_start, window=window,
@@ -203,6 +190,60 @@ def attn_write(buf, view_shape, layer, write_eids, positions, k, v):
     tpp = view_shape[3]
     return A.write_token_kv(buf, view_shape, layer, write_eids,
                             positions % tpp, k, v)
+
+
+def paged_q_block(kv_heads: int, groups: int) -> int:
+    """Query tokens per prefill tile of the in-place kernel: about 128
+    (token, head) rows (decode tokens always take one-token tiles).
+    ``ModelRunner`` sizes its ``kv_pages_read`` count with it."""
+    return max(1, 128 // PK.padded_heads(kv_heads * groups))
+
+
+def paged_pages_per_wave(view_shape) -> int:
+    """Pages per DMA wave of the in-place kernel: about 2048 K rows, at
+    most 16 pages (the v5e optimum at internlm2's and granite's widths)."""
+    tpp, kvl, d = view_shape[3:]
+    return max(1, min(16, 2048 * PK.LANES // (tpp * kvl * d)))
+
+
+def paged_work(view_shape, groups, seg_ids, positions, page_start,
+               page_count, *, window=0):
+    """The in-place kernel's work plan for one KV type and step (shared
+    by every layer of the type). seg_ids/positions: (1, T);
+    page_start/page_count: (S,)."""
+    return PK.plan_work(seg_ids[0], positions[0], page_start, page_count,
+                        q_block=paged_q_block(view_shape[4], groups),
+                        tokens_per_page=view_shape[3], window=window)
+
+
+def attn_paged(p, x, buf, view_shape, dist: Dist, *, layer, kv_local,
+               head_dim, positions, write_eids, page_ids, work, window=0,
+               rope_theta=1e6, mrope_positions=None, norm_eps=1e-5):
+    """Packed self-attention read IN PLACE from the unified buffer: write
+    this step's K/V into its pages first, then one Pallas call
+    (``kernels.paged_attention``) attends every segment's pages through
+    the page table, the chunk's own slots included, under the one rule
+    ``kseg == qseg and kpos <= qpos`` (plus the window). Nothing of the
+    pool is gathered or copied, and the buffer carry stays in place: the
+    kernel reads the newest buffer and no older version outlives the
+    write. Returns (x_out, buf)."""
+    b, t, _ = x.shape
+    xn = rms_norm(x, p["attn_norm"], norm_eps)
+    q, k, v = qkv_proj(p, xn, kv_local=kv_local, head_dim=head_dim,
+                       positions=positions, rope_theta=rope_theta,
+                       mrope_positions=mrope_positions)
+    buf = attn_write(buf, view_shape, layer, write_eids, positions, k, v)
+    vp, nl = view_shape[:2]
+    rows = buf.reshape(vp * nl, -1, buf.shape[-1])
+    qf = q * (1.0 / head_dim ** 0.5)              # the ref path's bf16 scale
+    out = PK.paged_attention(
+        qf[0], rows, page_ids, work, layer, num_layers=nl,
+        tokens_per_page=view_shape[3], window=window,
+        q_block=paged_q_block(kv_local, q.shape[3]),
+        pages_per_wave=paged_pages_per_wave(view_shape),
+        interpret=jax.default_backend() != "tpu")
+    y = dense(out.reshape(b, t, -1).astype(x.dtype), p["o"])
+    return x + psum_tp(y, dist), buf
 
 
 def attn_cached(p, x, buf, view_shape, dist: Dist, *, layer, kv_local,
@@ -400,47 +441,6 @@ def _bh_out(out, kvl, g):
     """(BH,T,D) kernel output back to (1,T,KVL,G,D)."""
     bh, t, d = out.shape
     return out.reshape(kvl, g, t, d).transpose(2, 0, 1, 3)[None]
-
-
-def packed_kernel_attention(q, k_old, v_old, slot_pos, slot_seg, k_fresh,
-                            v_fresh, positions, seg_ids, chunk_start, *,
-                            window=0):
-    """Packed serve attention via the Pallas varlen kernel: ONE
-    segment-block-sparse flash call over [old page slots ++ fresh chunk
-    K/V], replacing the ref path's two-part partials merge.
-
-    Old slots are gated by their segment's chunk start (parity with the
-    ref's strict ``slot_pos < chunk_start`` mask): a scatter-max over the
-    token stream recovers each segment's chunk start, and slots at or past
-    it — plus dead/pad slots (seg -2) — are re-tagged seg -2 so they never
-    match. Fresh tokens ride with kv_pos = positions, so the kernel's
-    ``kpos <= qpos`` rule reproduces the ref's intra-chunk causal mask;
-    old valid slots always have pos < chunk_start <= qpos, so the same
-    rule is a no-op for them.
-
-    Single-shard only (the kernel returns normalized output; callers with
-    kv_groups/sp_axis keep the ref partials path). q: (1,T,KVL,G,D);
-    k_old/v_old: (1,S,KVL,D); k_fresh/v_fresh: (1,T,KVL,D). Returns
-    (1,T,KVL,G,D) in q.dtype; rows with no visible KV come out zero."""
-    b, t, kvl, g, d = q.shape
-    s = k_old.shape[1]
-    sid = seg_ids[0]
-    cs = jnp.broadcast_to(chunk_start, (b, t))[0]
-    seg_cs = jnp.full((t,), -1, jnp.int32).at[jnp.clip(sid, 0, t - 1)].max(
-        jnp.where(sid >= 0, cs, -1))
-    slot_cs = jnp.take(seg_cs, jnp.clip(slot_seg[0], 0, t - 1))
-    live = (slot_seg[0] >= 0) & (slot_pos[0] < slot_cs)
-    kv_seg = jnp.concatenate([jnp.where(live, slot_seg[0], -2), sid])
-    kv_pos = jnp.concatenate([slot_pos[0], positions[0]])
-    kk = jnp.concatenate([k_old, k_fresh], axis=1)
-    vv = jnp.concatenate([v_old, v_fresh], axis=1)
-    qbh, kbh, vbh = _bh_streams(q, kk, vv, g)
-    blk_q, blk_k = sparse_blocks(t, s + t)
-    out = flash_attention_varlen_tpu(
-        qbh, kbh, vbh, sid, kv_seg, positions[0], kv_pos, window=window,
-        blk_q=blk_q, blk_k=blk_k,
-        interpret=jax.default_backend() != "tpu")
-    return _bh_out(out, kvl, g)
 
 
 def packed_cross_attn_kernel(q, k_all, v_all, slot_pos, slot_seg, seg_ids,

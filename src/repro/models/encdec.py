@@ -40,6 +40,9 @@ class EncDecLM(DecoderLM):
         self.is_moe = False
         self.max_dec_pos = min(MAX_DEC_POS, 32768 + 8)
 
+    def attends_in_place(self, attention_impl: str, lanes: int) -> bool:
+        return False        # self-attention stays on the gather + ref path
+
     def kv_specs(self) -> Tuple[KVCacheSpec, ...]:
         cfg = self.cfg
         return (
@@ -218,8 +221,9 @@ class EncDecLM(DecoderLM):
             page_pos_ca = sq(batch.page_pos["cross_attn"])
         kv_groups = (None if ri["repl"] == 1 else
                      A.replica_groups(ri["kv_tp"], ri["repl"]))
-        # kernel dispatch is packed + single-shard only (the Pallas call
-        # returns normalized output; sharded partials keep the ref path)
+        # the kernel impl reaches cross-attention only (packed, single
+        # shard: the Pallas call returns normalized output, sharded
+        # partials keep the ref path); self-attention always takes ref
         use_kernel = (attention_impl == "kernel" and packed
                       and kv_groups is None)
 
@@ -281,42 +285,36 @@ class EncDecLM(DecoderLM):
             s = k_all.shape[1]
             chunk_start = (batch.chunk_start if packed
                            else positions[:, :1])
-            if use_kernel:
-                out = BA.packed_kernel_attention(
-                    q, k_all, v_all, slot_pos, slot_seg, k, v, positions,
-                    batch.seg_ids, chunk_start)
-                out = out.reshape(b, t, -1).astype(x.dtype)
+            if prefill or packed:
+                from .blocks_attn import _prefill_flash
+                o, m, l = _prefill_flash(q, k_all, v_all, slot_pos,
+                                         positions,
+                                         chunk_start=chunk_start,
+                                         window=0, q_seg=batch.seg_ids,
+                                         kv_seg=slot_seg)
             else:
-                if prefill or packed:
-                    from .blocks_attn import _prefill_flash
-                    o, m, l = _prefill_flash(q, k_all, v_all, slot_pos,
-                                             positions,
-                                             chunk_start=chunk_start,
-                                             window=0, q_seg=batch.seg_ids,
-                                             kv_seg=slot_seg)
-                else:
-                    mask = slot_pos[:, None, :] < chunk_start[:, :, None]
-                    o, m, l = A.attend_tokens(q, k_all, v_all, mask)
-                if kv_groups is not None:
-                    o, m, l = A.combine_partials(o, m, l, dist.tp_axis,
-                                                 groups=kv_groups)
-                # fresh intra-chunk part
-                if packed:
-                    mask_f = A.segment_mask(batch.seg_ids, positions,
-                                            batch.seg_ids, positions)
-                    of, mf, lf = A.attend_tokens(q, k, v, mask_f)
-                elif t == 1:
-                    mask_f = jnp.ones((b, 1, 1), bool)
-                    of, mf, lf = A.attend_tokens(q, k, v, mask_f)
-                elif t <= 256:
-                    mask_f = positions[:, None, :] <= positions[:, :, None]
-                    of, mf, lf = A.attend_tokens(q, k, v, mask_f)
-                else:
-                    of, mf, lf = A.flash_attention_partials(q, k, v,
-                                                            causal=True)
-                o, m, l = A.merge_partials(o, m, l, of, mf, lf)
-                out = A.finalize_softmax(o, l).reshape(b, t, -1)
-                out = out.astype(x.dtype)
+                mask = slot_pos[:, None, :] < chunk_start[:, :, None]
+                o, m, l = A.attend_tokens(q, k_all, v_all, mask)
+            if kv_groups is not None:
+                o, m, l = A.combine_partials(o, m, l, dist.tp_axis,
+                                             groups=kv_groups)
+            # fresh intra-chunk part
+            if packed:
+                mask_f = A.segment_mask(batch.seg_ids, positions,
+                                        batch.seg_ids, positions)
+                of, mf, lf = A.attend_tokens(q, k, v, mask_f)
+            elif t == 1:
+                mask_f = jnp.ones((b, 1, 1), bool)
+                of, mf, lf = A.attend_tokens(q, k, v, mask_f)
+            elif t <= 256:
+                mask_f = positions[:, None, :] <= positions[:, :, None]
+                of, mf, lf = A.attend_tokens(q, k, v, mask_f)
+            else:
+                of, mf, lf = A.flash_attention_partials(q, k, v,
+                                                        causal=True)
+            o, m, l = A.merge_partials(o, m, l, of, mf, lf)
+            out = A.finalize_softmax(o, l).reshape(b, t, -1)
+            out = out.astype(x.dtype)
             y = psum_tp(dense(out, ps["o"]), dist)
             x = x + y + ps["o_bias"].astype(y.dtype)
             # --- cross attention (pre-gathered encoder KV)

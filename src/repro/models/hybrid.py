@@ -43,6 +43,9 @@ class HybridLM(DecoderLM):
                                  cfg.mamba_conv_width, tp)
 
     # ------------------------------------------------------------ kv specs
+    def attends_in_place(self, attention_impl: str, lanes: int) -> bool:
+        return False        # the shared attention block interleaves with recurrent state
+
     def kv_specs(self) -> Tuple[KVCacheSpec, ...]:
         cfg, md = self.cfg, self.md
         return (
@@ -177,6 +180,8 @@ class HybridLM(DecoderLM):
 
     def _serve_body(self, params, buffer, batch: DecodeBatch, *, prefill,
                     attention_impl="ref"):
+        # attention_impl is accepted for serve_step signature parity but
+        # unused: the shared attention block takes the gather + ref path
         cfg, dist = self.cfg, self.dist
         params = self._squeeze_params(params)
         tokens = batch.tokens
@@ -248,8 +253,7 @@ class HybridLM(DecoderLM):
                 positions=positions, seq_lens=batch.seq_lens,
                 rope_theta=cfg.rope_theta, norm_eps=cfg.norm_eps,
                 prefill=prefill, sp_axis=sp_axis, kv_groups=kv_groups,
-                seg_ids=batch.seg_ids, chunk_start=batch.chunk_start,
-                impl=attention_impl)
+                seg_ids=batch.seg_ids, chunk_start=batch.chunk_start)
             x = BA.mlp_block(shared, x, dist, cfg.norm_eps)
             buf = BA.attn_write(buf, views["full_attn"], cyc, write_eids,
                                 positions, k, v)

@@ -20,6 +20,7 @@ from ..core.spec import KVCacheSpec, attention_spec
 from . import attention as A
 from . import blocks_attn as BA
 from .common import rms_norm
+from ..kernels.paged_attention.kernel import LANES, eligible
 from .params import PD, init_params, param_specs, param_struct
 from .rotary import mrope_positions as _mrope3
 from .tp import (Dist, embed_lookup, expand_gqa_kv, expand_gqa_o,
@@ -58,6 +59,8 @@ class DecodeBatch:
     seg_start_tok: Any = None    # (1, TT) i32 stream idx of segment's first tok
     seg_last_tok: Any = None     # (N_seg,) i32 stream idx of segment's last tok
     page_seg: Any = None         # type -> (S, B_loc, P) i32 owning segment
+    page_start: Any = None       # type -> (S, B_loc, N_seg) i32 first page
+    page_count: Any = None       # type -> (S, B_loc, N_seg) i32 of each seg
 
 
 jax.tree_util.register_dataclass(
@@ -66,7 +69,7 @@ jax.tree_util.register_dataclass(
                  "write_eids", "state_eids", "mm_embeds", "mm_mask",
                  "mrope_pos", "last_idx", "enc_embeds", "enc_write_eids",
                  "enc_lens", "seg_ids", "chunk_start", "seg_start_tok",
-                 "seg_last_tok", "page_seg"],
+                 "seg_last_tok", "page_seg", "page_start", "page_count"],
     meta_fields=[])
 
 
@@ -141,6 +144,17 @@ class DecoderLM:
         if self.cnt["swa"]:
             out[self.kv_prefix + "swa"] = shp
         return out
+
+    def attends_in_place(self, attention_impl: str, lanes: int) -> bool:
+        """Whether the PACKED serve step reads its KV pages in place
+        through ``kernels.paged_attention`` (``blocks_attn.attn_paged``):
+        the kernel impl, a single shard of each KV head (no replica groups,
+        no sequence parallelism), whole 128-lane K/V rows per token and a
+        head_dim of 64 or 128. Everything else takes the gather + ref
+        path. ``lanes``: the unified buffer's minor dimension."""
+        return (attention_impl == "kernel" and self.ri["repl"] == 1
+                and not self.dist.sp and lanes == LANES
+                and eligible(self.ri["kv_local"], self.cfg.head_dim))
 
     # ----------------------------------------------------------- template
     def template(self):
@@ -404,6 +418,10 @@ class DecoderLM:
             seg_last_tok=P(None) if packed else None,
             page_seg=({k: P(shard_dim_spec, "model") for k in batch.page_seg}
                       if packed else None),
+            page_start=({k: P(shard_dim_spec, "model")
+                         for k in batch.page_start} if packed else None),
+            page_count=({k: P(shard_dim_spec, "model")
+                         for k in batch.page_count} if packed else None),
         )
         buf_spec = P(shard_dim_spec, "model")
         out_logit_spec = (P(None, "model") if (sp or packed)
@@ -468,6 +486,50 @@ class DecoderLM:
         kv_groups = (None if ri["repl"] == 1 else
                      A.replica_groups(ri["kv_tp"], ri["repl"]))
 
+        in_place = packed and self.attends_in_place(attention_impl,
+                                                    buffer.shape[-1])
+        if in_place:
+            groups = ri["q_local"] // ri["kv_local"]
+            page_ids = {k: v[0] for k, v in tables.items()}
+            work = {}
+            for kind in set(self.period_kinds):
+                tname = self.kv_type_of_kind(kind)
+                work[tname] = BA.paged_work(
+                    views[tname], groups, batch.seg_ids, positions,
+                    sq(batch.page_start[tname])[0],
+                    sq(batch.page_count[tname])[0],
+                    window=cfg.sliding_window if kind == "swa" else 0)
+
+        def ffn(pj, x):
+            if self.is_moe:
+                x, _ = BA.moe_block(
+                    pj, x, dist, num_experts=cfg.num_experts,
+                    top_k=cfg.experts_per_token,
+                    capacity_factor=cfg.capacity_factor,
+                    norm_eps=cfg.norm_eps)
+                return x
+            return BA.mlp_block(pj, x, dist, cfg.norm_eps)
+
+        def cycle_in_place(carry, xs):
+            # each layer writes its own K/V rows, then reads them in place
+            x, buf = carry
+            layer_params, cycle = xs
+            for j, kind in enumerate(self.period_kinds):
+                pj = self._fsdp_gather(jax.tree.map(lambda a: a[j],
+                                                    layer_params))
+                tname = self.kv_type_of_kind(kind)
+                layer_in_type = cycle * self.cnt[kind] + self.rank_in_period[j]
+                x, buf = BA.attn_paged(
+                    pj, x, buf, views[tname], dist, layer=layer_in_type,
+                    kv_local=ri["kv_local"], head_dim=cfg.head_dim,
+                    positions=positions, write_eids=write_eids[tname],
+                    page_ids=page_ids[tname], work=work[tname],
+                    window=cfg.sliding_window if kind == "swa" else 0,
+                    rope_theta=cfg.rope_theta, mrope_positions=mrope_pos,
+                    norm_eps=cfg.norm_eps)
+                x = ffn(pj, x)
+            return (x, buf), None
+
         def cycle_body(carry, xs):
             x, buf = carry
             layer_params, cycle = xs
@@ -494,17 +556,9 @@ class DecoderLM:
                     window=window, rope_theta=cfg.rope_theta,
                     mrope_positions=mrope_pos, norm_eps=cfg.norm_eps,
                     prefill=prefill, sp_axis=sp_axis, kv_groups=kv_groups,
-                    seg_ids=batch.seg_ids, chunk_start=batch.chunk_start,
-                    impl=attention_impl)
+                    seg_ids=batch.seg_ids, chunk_start=batch.chunk_start)
                 writes.append((tname, layer_in_type, k, v))
-                if self.is_moe:
-                    x, _ = BA.moe_block(
-                        pj, x, dist, num_experts=cfg.num_experts,
-                        top_k=cfg.experts_per_token,
-                        capacity_factor=cfg.capacity_factor,
-                        norm_eps=cfg.norm_eps)
-                else:
-                    x = BA.mlp_block(pj, x, dist, cfg.norm_eps)
+                x = ffn(pj, x)
             # phase 3: all writes at the end of the iteration
             for tname, layer_in_type, k, v in writes:
                 buf = BA.attn_write(buf, views[tname], layer_in_type,
@@ -512,7 +566,8 @@ class DecoderLM:
             return (x, buf), None
 
         (x, buffer), _ = jax.lax.scan(
-            cycle_body, (x, buffer), (stacked, jnp.arange(self.cycles)))
+            cycle_in_place if in_place else cycle_body, (x, buffer),
+            (stacked, jnp.arange(self.cycles)))
         x = rms_norm(x, params["final_norm"], cfg.norm_eps)
         if packed:
             # one logits row per SEGMENT: its last token in the stream

@@ -34,6 +34,9 @@ class RWKVLM(DecoderLM):
         self.rd = BS.rwkv6_dims(cfg.d_model, cfg.rwkv_head_size, tp)
         self.ri = {"kv_local": 1}  # unused
 
+    def attends_in_place(self, attention_impl: str, lanes: int) -> bool:
+        return False        # no attention layers
+
     def kv_specs(self) -> Tuple[KVCacheSpec, ...]:
         cfg, rd = self.cfg, self.rd
         return (

@@ -184,11 +184,15 @@ class EngineConfig:
     enable_prefix_caching: bool = True
     memory_mode: str = "jenga"       # "jenga" | "paged-baseline"
     geometry_mode: str = "lcm"        # "lcm" | "max"
-    # "ref"    — jnp reference attention (segment-block-sparse scan);
-    # "kernel" — the packed layout dispatches the Pallas varlen flash
-    #            kernel (interpret mode off-TPU, so CI exercises the real
-    #            kernel code path); padded/serial layouts keep ref.
-    attention_impl: str = "ref"
+    # "ref"    — gather the page stream, jnp reference attention
+    #            (segment-block-sparse scan);
+    # "kernel" — on the packed layout, attention reads the KV pages in
+    #            place through the Pallas paged kernel wherever the model
+    #            allows it (DecoderLM.attends_in_place), and cross-attention
+    #            takes the varlen kernel; interpret mode off-TPU, so CI
+    #            exercises the real kernel code. Everything else keeps ref.
+    # None resolves by backend: "kernel" on a TPU, "ref" elsewhere.
+    attention_impl: Optional[str] = None
     # Seed max_num_batched_tokens / max_prefill_tokens_per_step from the
     # roofline model and refine them online from StepMetrics (see
     # serving.autotune) instead of using the constants above.
@@ -234,11 +238,15 @@ class StepMetrics:
     # token vectors): vocab*4 per segment host-sampled vs 4 per segment
     # device-sampled.
     sampled_bytes_fetched: int = 0
-    # Attention-work counters (packed layout): (q block, KV block) tiles
-    # of the old-page self-attention streams this step scanned vs skipped
-    # by the segment-block-sparse schedule, and the modeled FLOPs / HBM
-    # bytes of the scanned tiles (host cost model — see
-    # ModelRunner._attn_block_stats).
+    # Page-layers the in-place paged kernel read this step, summed over
+    # the attention layers (host count, ModelRunner._pages_read); 0 on the
+    # gather path.
+    kv_pages_read: int = 0
+    # Attention-work counters of the gather path (packed layout): (q
+    # block, KV block) tiles of the old-page self-attention streams this
+    # step scanned vs skipped by the segment-block-sparse schedule, and
+    # the modeled FLOPs / HBM bytes of the scanned tiles (host cost model
+    # — see ModelRunner._attn_block_stats); 0 on the in-place path.
     kv_blocks_scanned: int = 0
     kv_blocks_skipped: int = 0
     attn_flops_modeled: float = 0.0
@@ -290,6 +298,9 @@ class Engine:
         self.model = model
         if cfg.batching_mode == "mixed":        # legacy alias for PR-1 mode
             cfg = dataclasses.replace(cfg, batching_mode="padded")
+        if cfg.attention_impl is None:
+            cfg = dataclasses.replace(cfg, attention_impl=(
+                "kernel" if jax.default_backend() == "tpu" else "ref"))
         self.cfg = cfg
         assert cfg.batching_mode in ("packed", "padded", "serial"), \
             cfg.batching_mode
@@ -366,7 +377,7 @@ class Engine:
         self.spec_rollback_pages = 0
         # runner attention-work totals already folded into StepMetrics
         # (the runner accumulates across dispatches; steps record deltas)
-        self._attn_seen = (0, 0, 0.0, 0.0)
+        self._attn_seen = (0, 0, 0, 0.0, 0.0)
         self._bytes_seen = 0
         self._sample_ms = 0.0           # host sampling time this step
 
@@ -661,8 +672,9 @@ class Engine:
         slots = self.runner.slots_dispatched - slots_before
         tokens = plan.total_tokens if tokens is None else tokens
         r = self.runner
-        attn_now = (r.kv_blocks_scanned, r.kv_blocks_skipped,
-                    r.attn_flops_modeled, r.attn_bytes_modeled)
+        attn_now = (r.kv_pages_read, r.kv_blocks_scanned,
+                    r.kv_blocks_skipped, r.attn_flops_modeled,
+                    r.attn_bytes_modeled)
         attn_delta = tuple(a - b for a, b in zip(attn_now, self._attn_seen))
         self._attn_seen = attn_now
         m = StepMetrics(
@@ -681,10 +693,11 @@ class Engine:
             dispatch_ms=disp_ms,
             host_sample_ms=self._sample_ms,
             sampled_bytes_fetched=r.bytes_fetched - self._bytes_seen,
-            kv_blocks_scanned=attn_delta[0],
-            kv_blocks_skipped=attn_delta[1],
-            attn_flops_modeled=attn_delta[2],
-            attn_bytes_modeled=attn_delta[3],
+            kv_pages_read=attn_delta[0],
+            kv_blocks_scanned=attn_delta[1],
+            kv_blocks_skipped=attn_delta[2],
+            attn_flops_modeled=attn_delta[3],
+            attn_bytes_modeled=attn_delta[4],
             recomputed_tokens=recomputed,
             phase_ms=phase_ms,
         )

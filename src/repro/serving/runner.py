@@ -14,8 +14,8 @@ of two layouts:
   FLOPs in the dense layers are proportional to the scheduler's token
   budget — a decode row co-scheduled with a 512-token prefill chunk no
   longer pays 512 tokens of padding. Token buckets are pow2 up to 16 then
-  multiples of 16 (see ``_tok_bucket``), so jit retraces stay bounded while
-  stream padding waste stays under ~10% on decode-heavy mixed steps.
+  four per octave (see ``_tok_bucket``), so jit retraces stay bounded while
+  stream padding stays under 16 slots, or half of a long step.
 
 * PADDED (the PR-1 layout, kept for A/Bs): one row per sequence, padded to
   the ``(B=_pow2(n), T=_pow2(max_chunk))`` bucket with SENTINEL positions
@@ -78,11 +78,26 @@ def _pow2(n: int, lo: int = 1) -> int:
 
 def _tok_bucket(n: int) -> int:
     """Packed-stream token bucket: pow2 below 16 (decode-only steps hit
-    exact small buckets), then multiples of 16 — bounded retraces with
-    <= 15 pad slots per dispatch instead of pow2's up-to-50% waste."""
+    exact small buckets), then four buckets per octave, multiples of
+    ``max(16, pow2(n) / 4)``: under 16 pad slots up to 64 tokens and under
+    half the step above, while the serve-step programs a run compiles grow
+    with the log of its token range, not linearly (each program costs
+    seconds of set-up; a pad token costs only its share of the dense
+    layers, never attention on the in-place path)."""
     if n <= 16:
         return _pow2(n)
-    return 16 * (-(-n // 16))
+    q = max(16, _pow2(n) // 4)
+    return q * (-(-n // q))
+
+
+def _seg_bucket(n: int) -> int:
+    """Packed-step segment bucket: pow2 up to 16, then powers of 4. Only
+    per-segment metadata and logits rows scale with it, so the coarse
+    steps cost little and keep a full decode batch (100-256 segments) on
+    one program."""
+    if n <= 16:
+        return _pow2(n)
+    return 4 ** -(-(n - 1).bit_length() // 2)
 
 
 def _page_rows(buf, size: int):
@@ -198,6 +213,8 @@ class PreparedStep:
                 v[0, 0, 0, sl] = -1
             for v in a["page_seg"].values():
                 np.place(v, v == si, -2)
+            for v in a["page_count"].values():
+                v[0, 0, 0, si] = 0
         else:
             a["tokens"][si, :] = 0
             a["positions"][si, :] = SENTINEL_POS
@@ -301,10 +318,15 @@ class ModelRunner:
         self.tokens_dispatched = 0
         self.slots_dispatched = 0
         self.dispatch_count = 0
-        # attention-work counters (block-sparse observability): cumulative
-        # totals across dispatches; the engine records per-step deltas into
-        # StepMetrics. Host-modeled from the packed layout metadata — see
-        # _attn_block_stats.
+        # attention-work counters: cumulative totals across dispatches; the
+        # engine records per-step deltas into StepMetrics. Host-modeled from
+        # the packed layout metadata: the ref path's block-sparse scan (see
+        # _attn_block_stats), or the page-layers the in-place kernel reads
+        # (see _pages_read) when the model attends in place.
+        in_place = getattr(model, "attends_in_place", None)
+        self.in_place = in_place is not None and in_place(
+            attention_impl, self.buffer.shape[-1])
+        self.kv_pages_read = 0
         self.kv_blocks_scanned = 0
         self.kv_blocks_skipped = 0
         self.attn_flops_modeled = 0.0
@@ -486,6 +508,38 @@ class ModelRunner:
             bytes_ += hits * L * kb * cfg.num_kv_heads * cfg.head_dim * 2 * 2
         return dict(kv_blocks_scanned=scanned, kv_blocks_skipped=skipped,
                     attn_flops_modeled=flops, attn_bytes_modeled=bytes_)
+
+    def _pages_read(self, items, mirrors) -> np.ndarray:
+        """Page-layers the in-place kernel reads in one packed step, per
+        segment, summed over the attention layers: each query tile
+        (``blocks_attn.paged_q_block`` tokens, one for a decode) reads its
+        segment's pages up to its last query position, from the first
+        page its window reaches. Mirrors ``paged_attention.plan_work``."""
+        from ..models.blocks_attn import paged_q_block
+        ri = self.model.ri
+        qb = paged_q_block(ri["kv_local"], ri["q_local"] // ri["kv_local"])
+        n = len(items)
+        start = np.fromiter((st for _, _, st in items), np.int64, n)
+        end = start + np.fromiter((nt for _, nt, _ in items), np.int64, n)
+        tiles = -(-(end - start) // qb)
+        seg = np.repeat(np.arange(n), tiles)             # one entry per tile
+        q0 = start[seg] + qb * (np.arange(seg.size)
+                                - np.repeat(np.cumsum(tiles) - tiles, tiles))
+        last = np.minimum(q0 + qb, end[seg]) - 1
+        out = np.zeros((n,), np.int64)
+        for name, spec in self._table_specs.items():
+            if spec.kind not in ("full_attn", "swa"):
+                continue
+            tpp = spec.tokens_per_page
+            npg = np.fromiter((m.n.get(name, 0) for m in mirrors), np.int64, n)
+            hi = np.minimum(npg[seg], last // tpp + 1)
+            lo = 0
+            if spec.kind == "swa":
+                lo = np.maximum(0, (q0 - spec.sliding_window + 1) // tpp)
+            out += spec.num_layers * np.bincount(
+                seg, weights=np.maximum(hi - lo, 0), minlength=n).astype(
+                    np.int64)
+        return out
 
     # ----------------------------------------------------------- batching
     def prepare(self, items, packed: bool = True, sample: bool = False,
@@ -669,7 +723,8 @@ class ModelRunner:
             mrope_pos=mrope, last_idx=last_idx, enc_embeds=enc_embeds,
             enc_write_eids=enc_write, enc_lens=enc_lens,
             seg_ids=None, chunk_start=None, seg_start_tok=None,
-            seg_last_tok=None, page_seg=None)
+            seg_last_tok=None, page_seg=None, page_start=None,
+            page_count=None)
         # T==1 buckets take the cheap materialized decode path; any larger
         # bucket (or an encoder run) uses the chunked prefill path. Both are
         # exact for every row thanks to position-based masking.
@@ -693,7 +748,7 @@ class ModelRunner:
         assert n > 0
         total = sum(nt for _, nt, _ in items)
         TT = _tok_bucket(total)
-        S = _pow2(n)                                  # segment bucket
+        S = _seg_bucket(n)
         mirrors = [self._mirror(r.seq) for r, _, _ in items]
         p_need: Dict[str, int] = {}                   # flat page-stream cap
         for name in self._table_specs:
@@ -712,6 +767,8 @@ class ModelRunner:
                     for k, p in p_need.items()}
         page_seg = {k: np.full((1, 1, 1, p), -2, np.int32)
                     for k, p in p_need.items()}
+        page_start = {k: np.zeros((1, 1, 1, S), np.int32) for k in p_need}
+        page_count = {k: np.zeros((1, 1, 1, S), np.int32) for k in p_need}
         write_eids = {k: np.full((1, 1, 1, TT), -1, np.int32)
                       for k in p_need}
         state_eids = {s.name: np.full((1, S), -1, np.int32)
@@ -756,6 +813,8 @@ class ModelRunner:
                     tables[name][0, 0, 0, pc:pc + nm] = m.table[name][:nm]
                     page_pos[name][0, 0, 0, pc:pc + nm] = m.pos[name][:nm]
                     page_seg[name][0, 0, 0, pc:pc + nm] = si
+                    page_start[name][0, 0, 0, si] = pc
+                    page_count[name][0, 0, 0, si] = nm
                     page_cursor[name] = pc + nm
                 if spec.kind in ("full_attn", "swa"):
                     tpp = spec.tokens_per_page
@@ -785,14 +844,18 @@ class ModelRunner:
             enc_write_eids=enc_write, enc_lens=enc_lens,
             seg_ids=seg_ids, chunk_start=chunk_start,
             seg_start_tok=seg_start_tok, seg_last_tok=seg_last_tok,
-            page_seg=page_seg)
+            page_seg=page_seg, page_start=page_start, page_count=page_count)
         key = ("packed", S, TT, tuple(sorted(p_need.items())),
                has_mm, has_enc)
-        return arrs, {"key": key, "n": n, "prefill": True,
-                      "fresh_state": fresh_state, "pending": pending,
-                      "seg_off": seg_off, "tokens": total, "slots": TT,
-                      "attn_work": self._attn_block_stats(
-                          TT, seg_ids[0], page_seg)}
+        info = {"key": key, "n": n, "prefill": True,
+                "fresh_state": fresh_state, "pending": pending,
+                "seg_off": seg_off, "tokens": total, "slots": TT}
+        if self.in_place:
+            info["kv_pages_read"] = self._pages_read(items, mirrors)
+        else:
+            info["attn_work"] = self._attn_block_stats(TT, seg_ids[0],
+                                                       page_seg)
+        return arrs, info
 
     # ----------------------------------------------------------------- run
     def dispatch(self, params, prep: PreparedStep):
@@ -815,6 +878,10 @@ class ModelRunner:
         self.tokens_dispatched += info["tokens"] - dead_tokens
         self.slots_dispatched += info["slots"]
         self.dispatch_count += 1
+        pages = info.get("kv_pages_read")
+        if pages is not None:       # killed segments read nothing
+            self.kv_pages_read += int(pages.sum()) - int(
+                sum(pages[si] for si in prep.dead))
         aw = info.get("attn_work")
         if aw is not None:
             self.kv_blocks_scanned += aw["kv_blocks_scanned"]

@@ -1,45 +1,71 @@
-"""Paged decode attention kernel vs pure-jnp oracle: shape/dtype sweeps +
-hypothesis property (page permutation invariance)."""
+"""In-place paged attention kernel vs its pure-jnp oracle: decode-only
+shape/dtype sweeps, a sliding window, and a hypothesis property (page
+permutation invariance); plus the row-addressed buffer writes and gathers
+of the reference path."""
 import pytest
 
 pytest.importorskip("hypothesis")
 
 import hypothesis.strategies as st
-import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 from hypothesis import given, settings
 
-from repro.kernels.paged_attention.kernel import paged_decode_attention
-from repro.kernels.paged_attention.ref import paged_decode_attention_ref
+from repro.kernels.paged_attention.kernel import paged_attention, plan_work
+from repro.kernels.paged_attention.ref import paged_attention_ref
+
+LAYERS, LAYER = 3, 1
 
 
-def make_case(b, kvl, g, d, tpp, n_pages, vp, seed=0, dtype=jnp.float32,
-              window=0):
+def make_case(b, kvl, g, d, tpp, n_pages, vp, seed=0, dtype=jnp.float32):
+    """``b`` decode segments of ``n_pages`` pages each, drawn without
+    replacement from a pool of ``vp`` pages; each token sits at a random
+    position of its segment's pages. Returns the kernel's arguments as a
+    dict."""
     rng = np.random.default_rng(seed)
+    rpt = kvl * d // 128
+    rows = jnp.asarray(rng.standard_normal(
+        (vp * LAYERS, 2 * tpp * rpt, 128)), dtype)
     q = jnp.asarray(rng.standard_normal((b, kvl, g, d)), dtype)
-    kv = jnp.asarray(rng.standard_normal((vp, 2, tpp, kvl, d)), dtype)
-    # each seq: pages drawn without replacement from the pool
-    tables = np.stack([rng.choice(vp, n_pages, replace=False)
-                       for _ in range(b)]).astype(np.int32)
-    page_pos = (np.arange(n_pages, dtype=np.int32) * tpp)[None].repeat(b, 0)
-    positions = rng.integers(1, n_pages * tpp, b).astype(np.int32)
-    return q, kv, jnp.asarray(tables), jnp.asarray(page_pos), \
-        jnp.asarray(positions)
+    ids = np.concatenate([rng.choice(vp, n_pages, replace=False)
+                          for _ in range(b)]).astype(np.int32)
+    return dict(q=q, rows=rows, page_ids=jnp.asarray(ids),
+                seg_ids=jnp.arange(b, dtype=jnp.int32),
+                positions=jnp.asarray(rng.integers(1, n_pages * tpp, b),
+                                      jnp.int32),
+                page_start=jnp.arange(b, dtype=jnp.int32) * n_pages,
+                page_count=jnp.full((b,), n_pages, jnp.int32), tpp=tpp)
+
+
+def run_kernel(c, window=0, page_ids=None, rows=None):
+    work = plan_work(c["seg_ids"], c["positions"], c["page_start"],
+                     c["page_count"], q_block=2, tokens_per_page=c["tpp"],
+                     window=window)
+    return paged_attention(
+        c["q"], c["rows"] if rows is None else rows,
+        c["page_ids"] if page_ids is None else page_ids, work, LAYER,
+        num_layers=LAYERS, tokens_per_page=c["tpp"], window=window,
+        q_block=2, pages_per_wave=2, interpret=True)
+
+
+def run_ref(c, window=0):
+    return paged_attention_ref(
+        c["q"], c["rows"], c["page_ids"], c["seg_ids"], c["positions"],
+        c["page_start"], c["page_count"], LAYER, num_layers=LAYERS,
+        tokens_per_page=c["tpp"], window=window)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("b,kvl,g,d,tpp,n_pages", [
-    (2, 1, 4, 32, 8, 4),
+    (2, 2, 4, 64, 8, 4),
     (3, 2, 2, 64, 16, 3),
     (1, 4, 1, 128, 8, 6),
 ])
 def test_kernel_matches_ref_sweep(b, kvl, g, d, tpp, n_pages, dtype):
     case = make_case(b, kvl, g, d, tpp, n_pages, vp=n_pages * b + 3,
                      dtype=dtype)
-    out_k = paged_decode_attention(*case, interpret=True)
-    out_r = paged_decode_attention_ref(*case)
+    out_k = run_kernel(case)
+    out_r = run_ref(case)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
     np.testing.assert_allclose(np.asarray(out_k, np.float32),
                                np.asarray(out_r, np.float32),
@@ -48,9 +74,9 @@ def test_kernel_matches_ref_sweep(b, kvl, g, d, tpp, n_pages, dtype):
 
 @pytest.mark.parametrize("window", [4, 16])
 def test_kernel_sliding_window(window):
-    case = make_case(2, 1, 2, 32, 8, 5, vp=16, window=window)
-    out_k = paged_decode_attention(*case, window=window, interpret=True)
-    out_r = paged_decode_attention_ref(*case, window=window)
+    case = make_case(2, 2, 2, 64, 8, 5, vp=16)
+    out_k = run_kernel(case, window=window)
+    out_r = run_ref(case, window=window)
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                atol=2e-5, rtol=2e-5)
 
@@ -61,18 +87,16 @@ def test_kernel_sliding_window(window):
 def test_page_id_permutation_invariance(seed, tpp, n_pages):
     """Jenga invariant: exec page ids are arbitrary — permuting which
     physical pages hold the data must not change attention output."""
-    b, kvl, g, d = 2, 1, 2, 32
     vp = 24
-    q, kv, tables, page_pos, positions = make_case(
-        b, kvl, g, d, tpp, n_pages, vp, seed=seed)
-    out1 = paged_decode_attention_ref(q, kv, tables, page_pos, positions)
-    # move every page's content to a permuted slot; update tables
-    rng = np.random.default_rng(seed + 1)
-    perm = rng.permutation(vp)
-    kv2 = jnp.asarray(np.asarray(kv)[np.argsort(perm)])
-    tables2 = jnp.asarray(perm[np.asarray(tables)])
-    out2k = paged_decode_attention(q, kv2, tables2, page_pos, positions,
-                                   interpret=True)
+    case = make_case(2, 2, 1, 64, tpp, n_pages, vp, seed=seed)
+    out1 = run_ref(case)
+    # move every page's content to a permuted slot; update the page ids
+    perm = np.random.default_rng(seed + 1).permutation(vp)
+    pages = np.asarray(case["rows"]).reshape(vp, LAYERS,
+                                             *case["rows"].shape[1:])
+    rows2 = jnp.asarray(pages[np.argsort(perm)].reshape(case["rows"].shape))
+    ids2 = jnp.asarray(perm[np.asarray(case["page_ids"])].astype(np.int32))
+    out2k = run_kernel(case, page_ids=ids2, rows=rows2)
     np.testing.assert_allclose(np.asarray(out1), np.asarray(out2k),
                                atol=3e-5, rtol=3e-5)
 

@@ -14,7 +14,7 @@ import numpy as np
 import pytest
 
 from repro.serving import Request, SamplingParams
-from repro.serving.runner import _tok_bucket
+from repro.serving.runner import _seg_bucket, _tok_bucket
 
 
 from conftest import make_engine
@@ -22,16 +22,25 @@ from conftest import make_engine
 
 # ---------------------------------------------------------------- bucketing
 def test_tok_bucket_shape():
-    """pow2 below 16 (exact small decode steps), multiples of 16 above —
-    bounded retraces with <= 15 pad slots per dispatch."""
+    """pow2 below 16 (exact small decode steps), then four buckets per
+    octave — multiples of 16 up to 64 tokens, of 32 up to 128, of 64 up to
+    256, of 128 up to 512 — so pad slots stay under 16, or under half the
+    step above 64 tokens, and a run meets few serve-step programs;
+    segments bucket to powers of two up to 16, then powers of four."""
     assert [_tok_bucket(n) for n in (1, 2, 3, 7, 8, 9, 16)] == \
         [1, 2, 4, 8, 8, 16, 16]
     assert _tok_bucket(17) == 32
-    assert _tok_bucket(71) == 80
+    assert _tok_bucket(71) == 96
     assert _tok_bucket(255) == 256
-    for n in range(17, 400):
+    assert _tok_bucket(257) == 384
+    assert len({_tok_bucket(n) for n in range(257, 513)}) == 2
+    for n in range(17, 1100):
         b = _tok_bucket(n)
-        assert b >= n and b - n < 16 and b % 16 == 0
+        q = max(16, 1 << (n - 1).bit_length() >> 2)
+        assert b >= n and b - n < q and b % q == 0
+        assert b - n < max(16, n / 2)
+    assert [_seg_bucket(n) for n in (1, 3, 16, 17, 64, 65, 150, 256, 257)] \
+        == [1, 4, 16, 64, 64, 256, 256, 256, 1024]
 
 
 def test_packed_single_decode_is_one_slot():

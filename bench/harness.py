@@ -3,18 +3,21 @@
 The flow (``run_cell``):
 
 1. set-up: the model from the cell's configuration file, weights made on
-   the device from the seed (``bench/weights.py``), the ``Engine`` with its
-   KV pool, a rehearsal that serves the run's own stream once so that its
-   serve-step shapes compile (see ``rehearse``), then a fresh engine and a
-   warm-up that serves the stream until its caches are in steady state;
+   the device from the seed by the module of the architecture the
+   configuration names (``bench/archs/<arch>.py``; ``bench/spec.py`` says
+   what it provides), the ``Engine`` with its KV pool, a rehearsal that
+   serves the run's own stream once so that its serve-step shapes compile
+   (see ``rehearse``), then a fresh engine and a warm-up that serves the
+   stream until its caches are in steady state;
 2. the window: ``--seconds`` of open-loop serving (``bench/serve.py``);
    with ``--trace 1`` the profiler records its last seconds;
 3. for a rate cell, a drain after the window, so that every request due
    in the window is timed to its real first token (capped);
 4. ``memory_peak_bytes``, then the program's state is freed and the plain
-   reference (``bench/reference.py``) is run over a sample, drawn from the
-   seed, of the requests the window finished (and of those still running,
-   with the tokens they were served), the longest among them;
+   reference (the architecture module's ``gaps``) is run over a sample,
+   drawn from the seed, of the requests the window finished (and of those
+   still running, with the tokens they were served), the longest among
+   them;
 5. the result line.
 """
 from __future__ import annotations
@@ -32,8 +35,6 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from . import reference, weights
-from .flops import FlopModel
 from .peaks import chip_peaks
 from .serve import Feeder
 from .spec import Bench
@@ -150,13 +151,14 @@ def build_model(cfg: dict):
     return build(ModelConfig(**cfg["model"]), single_device_dist())
 
 
-def make_params(cfg: dict, seed: int, model):
-    """The weights as the model serves them, made on the device."""
+def make_params(arch, cfg: dict, seed: int, model):
+    """The weights as the model serves them, made on the device by the
+    configuration's architecture module."""
     import jax
 
     from repro.serving.engine import serving_params
     want = jax.eval_shape(serving_params, model.struct())
-    params = weights.program_tree(cfg["model"], seed, want)
+    params = arch.program_tree(cfg["model"], seed, want)
     return jax.block_until_ready(params)
 
 
@@ -254,6 +256,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     bench = Bench(root)
     cell = bench.cell(workload)
     cfg = bench.config(cell["config"])
+    arch = bench.arch(cell["config"])
     mix = bench.mix(cell["traffic"]) if mix is None else mix
     devs = jax.devices()
     dev = devs[0]
@@ -270,10 +273,10 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
 
     kind = "rate" if mix["arrival"]["process"] == "poisson" else "batch"
     m = cfg["model"]
-    flop_model = FlopModel.from_config(cfg)
+    flop_model = arch.flop_model(cfg)
     t_init0 = clock()
     model = build_model(cfg) if model is None else model
-    params = make_params(cfg, seed, model)
+    params = make_params(arch, cfg, seed, model)
     eng = new_engine(cfg, model, params)
     t_init1 = clock()
     total_units = eng.mgr.geometry.total_units
@@ -391,7 +394,7 @@ def run_cell(root: Path, workload: str, seed: int, seconds: float,
     gc.collect()
     _log(f"device bytes in use after the engine is freed: "
          f"{(dev.memory_stats() or {}).get('bytes_in_use', 'not reported')}")
-    verdict = (_check(bench, cell, cfg, seed, sample, control) if check
+    verdict = (_check(arch, cfg, seed, sample, control) if check
                else {"correct": False, "numbers": {}})
     result = {"correct": verdict["correct"], "attempted": attempted,
               "failed": failed, "metrics": metrics,
@@ -454,13 +457,12 @@ def _end_to_end(bench: Bench, cell: str, records, w0, w1, t_close,
     return out
 
 
-def _check(bench: Bench, cell: dict, cfg: dict, seed: int, sample: list,
-           control: bool) -> dict:
-    """The plain reference over the sampled requests: the widest gap by
-    which a served token's logit lies below the reference's best. With
-    ``control`` the token read at each position is the one the fp8 control
-    puts first, in the place of the served one, and the same limit judges
-    it: the control has to come out not correct."""
+def _check(arch, cfg: dict, seed: int, sample: list, control: bool) -> dict:
+    """The architecture's plain reference over the sampled requests: the
+    widest gap by which a served token's logit lies below the reference's
+    best. With ``control`` the token read at each position is the one the
+    fp8 control puts first, in the place of the served one, and the same
+    limit judges it: the control has to come out not correct."""
     limit = float(cfg["check"]["max_logit_gap"])
     m = cfg["model"]
     if not sample:
@@ -469,11 +471,11 @@ def _check(bench: Bench, cell: dict, cfg: dict, seed: int, sample: list,
             "logit_gap": {"value": None, "limit": limit},
             "tokens": {"value": 0, "limit": 1}}}
     t0 = time.perf_counter()
-    w = weights.make(m, seed)
+    w = arch.make(m, seed)
     mode = "fp8" if control else "fp32"
     worst, n_tok = 0.0, 0
     for prompt, output in sample:
-        g = reference.gaps(w, m, prompt, output, mode=mode)
+        g = arch.gaps(w, m, prompt, output, mode=mode)
         worst = max(worst, float(g.max()))
         n_tok += len(output)
     del w

@@ -59,11 +59,11 @@ def step_ms_decode(run) -> Optional[float]:
 
 
 def mfu(run) -> Optional[float]:
-    """The whole step's share of the chip's bf16 peak: model FLOPs
-    (``bench/flops.py``) of the steps the profiler traced, over the seconds
-    in which the trace shows an operation on the device. The loop is
-    synchronous, so each traced step's device work lies inside the traced
-    window, and nothing else runs on the device there."""
+    """The whole step's share of the chip's bf16 peak: model FLOPs (the
+    architecture's ``flop_model``) of the steps the profiler traced, over
+    the seconds in which the trace shows an operation on the device. The
+    loop is synchronous, so each traced step's device work lies inside the
+    traced window, and nothing else runs on the device there."""
     tr = run.trace
     if not tr or tr["busy_s"] <= 0:
         return None

@@ -43,7 +43,7 @@ class StepRecord:
     t1: float
     metrics: object                          # repro StepMetrics
     prefill: bool                            # carried prompt tokens
-    flops: float = 0.0                       # bench/flops.py model FLOPs
+    flops: float = 0.0                       # model FLOPs (flop_model)
 
 
 class Feeder:

@@ -1,19 +1,22 @@
 """The harness end to end at a tiny size on the CPU.
 
 Each test builds a benchmark root of its own in a temporary directory: a
-``BENCHMARK.json``, a configuration, a traffic mix and a per-layer metric
-that exist only as new files there. The run skips the harness's look for a
-chip and uses a virtual clock (1 ms per reading, sleeps advance it), so it
-is deterministic and quick. Faults are planted under the timed path to see
-``correct`` come out false.
+``BENCHMARK.json``, a configuration, its architecture module, a traffic
+mix and a per-layer metric that exist only as new files there. The run
+skips the harness's look for a chip and uses a virtual clock (1 ms per
+reading, sleeps advance it), so it is deterministic and quick. Faults are
+planted under the timed path to see ``correct`` come out false.
 """
 import json
+import shutil
 import sys
 from pathlib import Path
 
+import numpy as np
 import pytest
 
 from bench import harness, reference, weights
+from bench.flops import FlopModel
 from bench.spec import Bench
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -58,7 +61,9 @@ def make_root(tmp: Path) -> Path:
     (tmp / "bench" / "configs").mkdir(parents=True)
     (tmp / "bench" / "traffic").mkdir()
     (tmp / "bench" / "metrics").mkdir()
-    cfg = {"model": TINY_MODEL,
+    (tmp / "bench" / "archs").mkdir()
+    shutil.copy(BENCH / "archs" / "dense_decoder.py", tmp / "bench" / "archs")
+    cfg = {"arch": "dense_decoder", "model": TINY_MODEL,
            "engine": {"kv_pool_bytes": 4 << 20, "max_running": 16,
                       "max_num_batched_tokens": 64, "chunk_size": 64},
            "check": {"max_logit_gap": TINY_LIMIT}}
@@ -149,6 +154,9 @@ def test_new_files_alone_define_a_cell(root):
     b = Bench(root)
     assert b.cell("tiny-dense.tiny_chat")["traffic"] == "tiny_chat"
     assert b.config("tiny-dense")["model"]["d_model"] == 64
+    # the architecture is the root's own module, not the installed one
+    assert Path(b.arch("tiny-dense").__file__) == \
+        root / "bench" / "archs" / "dense_decoder.py"
     assert b.mix("tiny_chat")["arrival"]["rate_per_s"] == 4.0
     assert sorted(b.readers("tiny-dense.tiny_chat")) == [
         "prefix_hit_frac", "queue_wait_p95_ms", "steps_in_window"]
@@ -270,3 +278,168 @@ def test_run_exits_nonzero_without_a_chip(capsys):
     out = capsys.readouterr().out
     assert rc != 0
     assert "platform=cpu" in out and '"correct"' not in out
+
+
+# A second architecture, as a configuration of one would bring it: the
+# dense decoder's weights and FLOP count, and a reference over the weights
+# of the seed plus OFFSET, so that with OFFSET 1 it disagrees with what the
+# program serves. It records which of its functions the run called.
+PLANTED_ARCH = '''
+from bench import reference, weights
+from bench.flops import FlopModel
+
+CALLS = []
+
+
+def make(m, seed):
+    CALLS.append("make")
+    return weights.make(m, seed + {offset})
+
+
+def program_tree(m, seed, expected):
+    CALLS.append("program_tree")
+    return weights.program_tree(m, seed, expected)
+
+
+def gaps(w, m, prompt, output, mode="fp32"):
+    CALLS.append("gaps")
+    return reference.gaps(w, m, prompt, output, mode=mode)
+
+
+def flop_model(cfg):
+    CALLS.append("flop_model")
+    return FlopModel.from_config(cfg)
+'''
+
+
+def plant_arch(root: Path, offset: int) -> str:
+    """Adds an architecture, a configuration that names it and a cell, as
+    new files and entries only; returns the cell."""
+    (root / "bench/archs/tiny_planted.py").write_text(
+        PLANTED_ARCH.format(offset=offset))
+    cfg = json.loads((root / "bench/configs/tiny-dense.json").read_text())
+    cfg["arch"] = "tiny_planted"
+    (root / "bench/configs/tiny-planted.json").write_text(json.dumps(cfg))
+    spec = json.loads((root / "BENCHMARK.json").read_text())
+    spec["configs"].append({"name": "tiny-planted", "source": "test",
+                            "file": "bench/configs/tiny-planted.json",
+                            "reduced": [], "why": "test"})
+    cell = "tiny-planted.tiny_backlog"
+    spec["workloads"].append({"name": cell, "config": "tiny-planted",
+                              "traffic": "tiny_backlog", "chips": 1,
+                              "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if "tiny-dense.tiny_backlog" in m.get("workloads", ()):
+            m["workloads"].append(cell)
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    return cell
+
+
+@pytest.mark.parametrize("offset", [0, 1],
+                         ids=["reference_agrees", "reference_disagrees"])
+def test_a_new_architecture_is_new_files_alone(root, monkeypatch, offset):
+    """A configuration of another architecture runs on that architecture's
+    module: its served weights, its FLOP count and its reference, so that a
+    reference that disagrees with what is served turns ``correct`` false."""
+    cell = plant_arch(root, offset)
+    loaded, arch = [], Bench.arch
+    monkeypatch.setattr(Bench, "arch", lambda self, config:
+                        loaded.append(arch(self, config)) or loaded[-1])
+    res = run(root, cell=cell)
+    (mod,) = loaded
+    assert Path(mod.__file__) == root / "bench/archs/tiny_planted.py"
+    assert mod.CALLS[:3] == ["flop_model", "program_tree", "make"]
+    assert set(mod.CALLS[3:]) == {"gaps"}
+    assert res["metrics"]["output_tokens_per_s"]["value"] > 0
+    assert res["correct"] is (offset == 0)
+    assert (res["check"]["logit_gap"]["value"] <= TINY_LIMIT) is (offset == 0)
+
+
+@pytest.mark.parametrize("fault", ["no_key", "no_module"])
+def test_a_config_without_its_architecture_stops_the_run(root, fault):
+    if fault == "no_key":
+        path = root / "bench/configs/tiny-dense.json"
+        cfg = json.loads(path.read_text())
+        del cfg["arch"]
+        path.write_text(json.dumps(cfg))
+    else:
+        (root / "bench/archs/dense_decoder.py").unlink()
+    with pytest.raises((ValueError, FileNotFoundError), match='"arch"'):
+        run(root)
+
+
+SHIPPED = sorted(p.stem for p in (BENCH / "configs").glob("*.json"))
+
+
+@pytest.fixture(scope="module")
+def shipped_root(tmp_path_factory):
+    """A root that lists every shipped configuration, with the shipped
+    architecture modules."""
+    root = tmp_path_factory.mktemp("shipped")
+    shutil.copytree(BENCH / "configs", root / "bench" / "configs")
+    shutil.copytree(BENCH / "archs", root / "bench" / "archs")
+    (root / "BENCHMARK.json").write_text(json.dumps({"configs": [
+        {"name": n, "file": f"bench/configs/{n}.json"} for n in SHIPPED]}))
+    return root
+
+
+def tiny_of(m: dict) -> dict:
+    """A shipped model at a tiny size, its other keys (tied or untied
+    unembedding, rope theta, norm epsilon) kept."""
+    return dict(m, num_layers=2, d_model=64, num_heads=4, num_kv_heads=2,
+                head_dim=16, d_ff=128, vocab_size=512)
+
+
+def same_tree(a, b) -> bool:
+    import jax
+    return (jax.tree.structure(a) == jax.tree.structure(b) and all(
+        x.dtype == y.dtype and np.array_equal(np.asarray(x), np.asarray(y))
+        for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b))))
+
+
+@pytest.mark.parametrize("name", SHIPPED)
+def test_dense_decoder_makes_the_same_weights(shipped_root, name):
+    """The module of a shipped configuration makes, bit for bit, the
+    weights ``bench/weights.py`` makes, canonical and as served."""
+    import jax
+
+    from repro.serving.engine import serving_params
+    b = Bench(shipped_root)
+    cfg = b.config(name)
+    assert cfg["arch"] == "dense_decoder"
+    m = tiny_of(cfg["model"])
+    arch = b.arch(name)
+    assert same_tree(arch.make(m, 2 ** 33 + 5), weights.make(m, 2 ** 33 + 5))
+    model = harness.build_model({"model": m})
+    want = jax.eval_shape(serving_params, model.struct())
+    assert same_tree(harness.make_params(arch, {"model": m}, 9, model),
+                     weights.program_tree(m, 9, want))
+
+
+@pytest.mark.parametrize("name", SHIPPED)
+def test_dense_decoder_reference_is_the_same(shipped_root, name):
+    """Its reference gives the gaps ``bench/reference.py`` gives, for the
+    served tokens and for the fp8 control's."""
+    b = Bench(shipped_root)
+    m = tiny_of(b.config(name)["model"])
+    arch = b.arch(name)
+    w = weights.make(m, 4)
+    prompt, output = list(range(3, 40)), [5, 9, 200, 7, 7]
+    for mode in ("fp32", "fp8"):
+        assert np.array_equal(arch.gaps(w, m, prompt, output, mode=mode),
+                              reference.gaps(w, m, prompt, output, mode=mode))
+    seq = prompt + output[:-1]
+    rows = list(range(len(prompt) - 1, len(seq)))
+    assert np.array_equal(reference.logits(arch.make(m, 4), m, seq, rows),
+                          reference.logits(w, m, seq, rows))
+
+
+@pytest.mark.parametrize("name", SHIPPED)
+def test_dense_decoder_counts_the_same_flops(shipped_root, name):
+    """At the published size its FLOP count is ``bench/flops.py``'s."""
+    b = Bench(shipped_root)
+    cfg = b.config(name)
+    got, want = b.arch(name).flop_model(cfg), FlopModel.from_config(cfg)
+    for lo, hi in ((0, 1), (0, 512), (300, 301), (1023, 4096)):
+        assert got.token_flops(lo, hi) == want.token_flops(lo, hi)
+    assert got.logits_flops() == want.logits_flops()
